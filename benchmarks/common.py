@@ -121,6 +121,39 @@ def save_result(name: str, payload) -> None:
         json.dump(payload, f, indent=1, default=str)
 
 
+#: virtual host devices the sharded sections run on without an accelerator
+CPU_SHARD_DEVICES = 8
+
+
+def sharded_rows(module: str, func: str) -> list:
+    """Rows of the sharded measurement ``module.func(max_shards=...)``.
+
+    On an accelerator it runs in this process over the visible devices
+    (a chip belongs to one process, so a child could not take it).  On
+    the CPU it runs in a child process whose environment asks XLA for
+    :data:`CPU_SHARD_DEVICES` virtual devices: that flag must be set
+    before JAX starts, so it is never set in this process."""
+    import importlib
+    import subprocess
+    import sys
+
+    import jax
+    if jax.default_backend() != "cpu":
+        return getattr(importlib.import_module(module), func)(
+            max_shards=jax.device_count())
+    env = dict(os.environ, PYTHONPATH="src", XLA_FLAGS=(
+        f"--xla_force_host_platform_device_count={CPU_SHARD_DEVICES}"))
+    code = (f"import json; from {module} import {func}; "
+            f"print(json.dumps({func}(max_shards={CPU_SHARD_DEVICES})))")
+    root = os.path.join(os.path.dirname(__file__), "..")
+    out = subprocess.run([sys.executable, "-c", code], cwd=root, env=env,
+                         capture_output=True, text=True, timeout=1800)
+    if out.returncode != 0:
+        raise RuntimeError(f"{module} sharded child failed:\n"
+                           f"{out.stderr[-3000:]}")
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
 def csv_line(name: str, us_per_call, derived: str = "") -> str:
     if us_per_call is None:
         us_per_call = float("nan")

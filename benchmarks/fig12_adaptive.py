@@ -22,8 +22,9 @@ Validates:
 * batched distances are bit-identical to per-source runs (checked here on
   every graph, every run — the serving path may not drift).
 
-Calibration artefacts cache under ``RESULTS_DIR/calibration`` — the
-second benchmark run reuses them (``cache: hit``).
+Calibration artefacts cache under ``RESULTS_DIR/calibration``, keyed
+on the device's platform and kind (``costmodel.cache_path``) — the
+second benchmark run on the same device reuses them (``cache: hit``).
 """
 
 from __future__ import annotations

@@ -28,98 +28,80 @@ and road (bounded-degree) families over S ∈ {1, 2, 4, 8} and reports:
   serialized in the emulator), so they use a reduced shard set — their
   absolute times price emulation, not TPU kernel quality.
 
-Honesty note: the shards here are *virtual* host devices carved out of
-one CPU (``XLA_FLAGS=--xla_force_host_platform_device_count=8``), so
-MTEPS vs S shows the *overhead* trend (combine cost, padding) rather
-than real multi-device speedup — the same caveat as every CPU-scaled
-figure in this suite (benchmarks/common.py).  The measurement runs in a
-subprocess because the device-count flag must be set before jax
-initializes; the parent stays single-device.
+On an accelerator the sweep runs in this process over the visible
+devices, up to their count.  On the CPU the shards are *virtual* host
+devices carved out of one CPU, in a child process whose environment
+sets ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
+(:func:`benchmarks.common.sharded_rows`), so MTEPS vs S there shows the
+*overhead* trend (combine cost, padding) rather than real multi-device
+speedup — the same caveat as every CPU-scaled figure in this suite.
 """
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
+import numpy as np
 
-from benchmarks.common import csv_line, fmt_rate, save_result
+from benchmarks.common import (csv_line, fmt_rate, safe_mteps, save_result,
+                               sharded_rows)
+from repro.core import engine, shard
+from repro.data import rmat_graph, road_grid_graph
 
 SHARD_COUNTS = [1, 2, 4, 8]
 #: interpret-mode Pallas serializes the kernel grid, so the pallas leg
 #: prices the endpoints of the shard axis rather than the full sweep
 PALLAS_SHARD_COUNTS = [1, 8]
 
-_CHILD = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import sys
-sys.path.insert(0, "src")
-import json
-import numpy as np
-from benchmarks.common import safe_mteps
-from repro.core import engine, shard
-from repro.data import rmat_graph, road_grid_graph
-
-SHARD_COUNTS = %s
-PALLAS_SHARD_COUNTS = %s
 GRAPHS = {
     "rmat": lambda: rmat_graph(scale=10, edge_factor=8, weighted=True,
                                seed=7),
     "road": lambda: road_grid_graph(side=48, weighted=True, seed=7),
 }
 
-rows = []
-for gname, make in GRAPHS.items():
-    g = make()
-    source = int(np.argmax(np.asarray(g.degrees)))
-    base = None
-    for backend in ("xla", "pallas"):
-        counts = SHARD_COUNTS if backend == "xla" else PALLAS_SHARD_COUNTS
-        for s_count in counts:
-            _, info = shard.partition(g, s_count, method="degree")
-            best = None
-            for i in range(3):                 # warm-up + best-of-2
-                res = engine.run(g, source, engine.make_strategy("WD"),
-                                 mode="fused", shards=s_count,
-                                 backend=backend)
-                if i and (best is None
-                          or res.traversal_seconds
-                          < best.traversal_seconds):
-                    best = res
-            if base is None:
-                base = best
-            tag = f"{gname}/{backend}/{s_count}"
-            assert np.array_equal(best.dist, base.dist), tag
-            assert best.iterations == base.iterations, tag
-            assert best.edges_relaxed == base.edges_relaxed, tag
-            rows.append({
-                "graph": gname, "backend": backend, "shards": s_count,
-                "iterations": best.iterations,
-                "edges_relaxed": best.edges_relaxed,
-                "traversal_s": best.traversal_seconds,
-                "setup_s": best.setup_seconds,
-                "mteps": safe_mteps(best),
-                "cut_share": info.cut_share,
-                "halo_bytes": info.halo_bytes,
-                "replica_exchange_bytes": 4 * g.num_nodes * s_count,
-                "edge_imbalance": info.edge_imbalance,
-            })
-print(json.dumps({"rows": rows}))
-""" % (SHARD_COUNTS, PALLAS_SHARD_COUNTS)
+
+def measure(max_shards: int) -> list:
+    """The sweep's rows, over the shard counts up to ``max_shards``
+    (the devices this process sees)."""
+    rows = []
+    for gname, make in GRAPHS.items():
+        g = make()
+        source = int(np.argmax(np.asarray(g.degrees)))
+        base = None
+        for backend in ("xla", "pallas"):
+            counts = SHARD_COUNTS if backend == "xla" else PALLAS_SHARD_COUNTS
+            for s_count in (c for c in counts if c <= max_shards):
+                _, info = shard.partition(g, s_count, method="degree")
+                best = None
+                for i in range(3):                 # warm-up + best-of-2
+                    res = engine.run(g, source, engine.make_strategy("WD"),
+                                     mode="fused", shards=s_count,
+                                     backend=backend)
+                    if i and (best is None
+                              or res.traversal_seconds
+                              < best.traversal_seconds):
+                        best = res
+                if base is None:
+                    base = best
+                tag = f"{gname}/{backend}/{s_count}"
+                assert np.array_equal(best.dist, base.dist), tag
+                assert best.iterations == base.iterations, tag
+                assert best.edges_relaxed == base.edges_relaxed, tag
+                rows.append({
+                    "graph": gname, "backend": backend, "shards": s_count,
+                    "iterations": best.iterations,
+                    "edges_relaxed": best.edges_relaxed,
+                    "traversal_s": best.traversal_seconds,
+                    "setup_s": best.setup_seconds,
+                    "mteps": safe_mteps(best),
+                    "cut_share": info.cut_share,
+                    "halo_bytes": info.halo_bytes,
+                    "replica_exchange_bytes": 4 * g.num_nodes * s_count,
+                    "edge_imbalance": info.edge_imbalance,
+                })
+    return rows
 
 
 def run(verbose: bool = True):
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    env["PYTHONPATH"] = "src"
-    root = os.path.join(os.path.dirname(__file__), "..")
-    out = subprocess.run([sys.executable, "-c", _CHILD], cwd=root, env=env,
-                         capture_output=True, text=True, timeout=1800)
-    if out.returncode != 0:
-        raise RuntimeError(f"fig15 child failed:\n{out.stderr[-3000:]}")
-    payload = json.loads(out.stdout.strip().splitlines()[-1])
+    payload = {"rows": sharded_rows("benchmarks.fig15_sharded", "measure")}
     save_result("fig15_sharded", payload)
     lines = []
     for r in payload["rows"]:

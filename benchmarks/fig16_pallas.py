@@ -21,24 +21,21 @@ scale-independent).
 
 Every row carries a ``shards`` field.  The single-device matrix above
 runs in-process (``shards=1``); a second **sharded** section re-runs WD
-at ``shards=8`` for both backends in a measurement subprocess (the
-device-count flag must be set before jax initializes —
-docs/sharding.md), parity-asserted against the single-device run, so
-the artifact exposes the full backend × shards axis the parity contract
-covers (docs/backends.md).
+at ``shards=8`` (at most the visible devices) for both backends,
+parity-asserted against the single-device run, so the artifact exposes
+the full backend × shards axis the parity contract covers
+(docs/backends.md).  On an accelerator it runs in this process; on the
+CPU in a child process with 8 virtual devices
+(:func:`benchmarks.common.sharded_rows`).
 """
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-
 import numpy as np
 
 from benchmarks.common import (csv_line, fmt_rate, run_strategy,
-                               safe_mteps, save_result)
+                               safe_mteps, save_result, sharded_rows)
+from repro.core import engine
 from repro.data import rmat_graph, road_grid_graph
 
 #: one power-law, one bounded-degree family (paper suite), scaled to the
@@ -57,73 +54,45 @@ FIG16_STRATEGIES = ["BS", "WD", "HP", "AD"]
 #: shard-count sweep, this section prices the backend axis at width
 FIG16_SHARDS = 8
 
-_SHARDED_CHILD = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import sys
-sys.path.insert(0, "src")
-import json
-import numpy as np
-from benchmarks.common import safe_mteps
-from repro.core import engine
-from repro.data import rmat_graph, road_grid_graph
-
-SHARDS = %d
-GRAPHS = {
-    "rmat": lambda: rmat_graph(scale=9, edge_factor=8, weighted=True,
-                               seed=7),
-    "road": lambda: road_grid_graph(side=24, weighted=True, seed=7),
-}
-
-rows = []
-for gname, make in GRAPHS.items():
-    g = make()
-    source = int(np.argmax(np.asarray(g.degrees)))
-    base = engine.run(g, source, engine.make_strategy("WD"), mode="fused")
-    runs = {}
-    for backend in ("xla", "pallas"):
-        best = None
-        for i in range(2):                     # warm-up (compile) + timed
-            res = engine.run(g, source, engine.make_strategy("WD"),
-                             mode="fused", shards=SHARDS, backend=backend)
-            best = res if i else None
-        tag = f"{gname}/{backend}"
-        assert np.array_equal(best.dist, base.dist), tag
-        assert best.iterations == base.iterations, tag
-        assert best.edges_relaxed == base.edges_relaxed, tag
-        runs[backend] = best
-    xla, pallas = runs["xla"], runs["pallas"]
-    rows.append({
-        "graph": gname, "strategy": "WD", "shards": SHARDS,
-        "iterations": xla.iterations,
-        "edges_relaxed": xla.edges_relaxed,
-        "xla_s": xla.traversal_seconds,
-        "pallas_s": pallas.traversal_seconds,
-        "mteps_xla": safe_mteps(xla),
-        "mteps_pallas": safe_mteps(pallas),
-        "pallas_over_xla": (
-            pallas.traversal_seconds / xla.traversal_seconds
-            if xla.traversal_seconds > 0 else 0.0),
-        "parity": "bit-identical",
-    })
-print(json.dumps({"rows": rows}))
-""" % FIG16_SHARDS
-
-
-def _sharded_rows():
-    """WD backend pair at ``shards=FIG16_SHARDS``, measured in a
-    subprocess (8 virtual devices), same row schema plus ``shards``."""
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    env["PYTHONPATH"] = "src"
-    root = os.path.join(os.path.dirname(__file__), "..")
-    out = subprocess.run([sys.executable, "-c", _SHARDED_CHILD], cwd=root,
-                         env=env, capture_output=True, text=True,
-                         timeout=1800)
-    if out.returncode != 0:
-        raise RuntimeError(f"fig16 sharded child failed:\n"
-                           f"{out.stderr[-3000:]}")
-    return json.loads(out.stdout.strip().splitlines()[-1])["rows"]
+def sharded_measure(max_shards: int) -> list:
+    """WD backend pair at ``min(FIG16_SHARDS, max_shards)`` shards,
+    parity-asserted against the single-device run; same row schema plus
+    ``shards``."""
+    shards = min(FIG16_SHARDS, max_shards)
+    rows = []
+    for gname, make in FIG16_GRAPHS.items():
+        g = make()
+        source = int(np.argmax(np.asarray(g.degrees)))
+        base = engine.run(g, source, engine.make_strategy("WD"),
+                          mode="fused")
+        runs = {}
+        for backend in ("xla", "pallas"):
+            best = None
+            for i in range(2):                 # warm-up (compile) + timed
+                res = engine.run(g, source, engine.make_strategy("WD"),
+                                 mode="fused", shards=shards,
+                                 backend=backend)
+                best = res if i else None
+            tag = f"{gname}/{backend}"
+            assert np.array_equal(best.dist, base.dist), tag
+            assert best.iterations == base.iterations, tag
+            assert best.edges_relaxed == base.edges_relaxed, tag
+            runs[backend] = best
+        xla, pallas = runs["xla"], runs["pallas"]
+        rows.append({
+            "graph": gname, "strategy": "WD", "shards": shards,
+            "iterations": xla.iterations,
+            "edges_relaxed": xla.edges_relaxed,
+            "xla_s": xla.traversal_seconds,
+            "pallas_s": pallas.traversal_seconds,
+            "mteps_xla": safe_mteps(xla),
+            "mteps_pallas": safe_mteps(pallas),
+            "pallas_over_xla": (
+                pallas.traversal_seconds / xla.traversal_seconds
+                if xla.traversal_seconds > 0 else 0.0),
+            "parity": "bit-identical",
+        })
+    return rows
 
 
 def run(verbose: bool = True):
@@ -156,7 +125,8 @@ def run(verbose: bool = True):
                 "parity": "bit-identical",
             })
 
-    rows.extend(_sharded_rows())
+    rows.extend(sharded_rows("benchmarks.fig16_pallas",
+                             "sharded_measure"))
 
     save_result("fig16_pallas", {"rows": rows})
     lines = []

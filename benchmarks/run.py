@@ -5,6 +5,8 @@ import sys
 
 
 def main() -> None:
+    from repro import compile_cache
+    compile_cache.enable()
     from benchmarks import (fig7_sssp, fig8_bfs, fig9_tradeoffs, fig10_ns,
                             fig11_chunking, fig12_adaptive, fig13_fused,
                             fig14_operators, fig15_sharded, fig16_pallas,

@@ -15,6 +15,7 @@ benchmark suite (see docs/benchmarks.md).
 
 import numpy as np  # noqa: F811
 
+from repro import compile_cache
 from repro.core import balance, engine
 from repro.core.graph import graph_stats
 from repro.data import rmat_graph
@@ -22,6 +23,7 @@ from repro.data import rmat_graph
 
 def main():
     import numpy as np
+    compile_cache.enable()
     g = rmat_graph(scale=13, edge_factor=8, weighted=True, seed=1)
     print(f"graph: {graph_stats(g)}")
     print(f"whole-graph node imbalance: {balance.graph_imbalance(g)}\n")

@@ -16,6 +16,7 @@ import argparse
 
 import numpy as np
 
+from repro import compile_cache
 from repro.data import make_graph
 from repro.serve import GraphServer, Request
 
@@ -36,6 +37,7 @@ def main():
     ap.add_argument("--algo", choices=["sssp", "bfs"], default="sssp")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
 
     g = make_graph(args.graph, weighted=(args.algo == "sssp"))
     rng = np.random.default_rng(args.seed)

@@ -1,39 +1,24 @@
-"""Version-compatibility shims for JAX APIs that moved between releases.
+"""The one ``shard_map`` entry point of the repo, for jax 0.9.0.
 
-The repo supports the jax pinned in ``requirements-dev.txt``
-(``jax>=0.4.20``), which spans two relocations of ``shard_map``:
-
-* ≤ 0.4.x / 0.5.x — ``jax.experimental.shard_map.shard_map`` with a
-  ``check_rep=`` kwarg;
-* ≥ 0.6 — ``jax.shard_map`` with the replication check renamed to
-  ``check_vma=``.
-
-Every ``shard_map`` call site in the repo (the sharded graph engine in
+The repo is tested on the stack pinned in ``requirements-dev.txt``
+(jax 0.9.0 / jaxlib 0.9.0, libtpu 0.0.34 on the chip).  Every
+``shard_map`` call site (the sharded graph engine in
 :mod:`repro.core.shard`, the expert-parallel MoE dispatch in
-:mod:`repro.moe.sharded`, tests) goes through :func:`shard_map` here so
-the version split lives in exactly one place.
+:mod:`repro.moe.sharded`, tests) goes through :func:`shard_map` here, so
+the replication-check default lives in exactly one place.
 """
 
 from __future__ import annotations
 
 import jax
 
-try:  # jax >= 0.6: public, top-level
-    _shard_map = jax.shard_map
-    _CHECK_KWARG = "check_vma"
-except AttributeError:  # jax 0.4.x / 0.5.x: experimental, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KWARG = "check_rep"
-
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across jax versions.
-
-    ``check`` maps onto ``check_vma`` (new) / ``check_rep`` (old).  It
-    defaults to **off** because the sharded engine's per-chunk monoid
-    combines (``pmin``/``pmax``/delta-``psum``) produce values that are
-    replicated *by construction* — identical collectives on identical
-    operands — which the older ``check_rep`` tracker cannot always prove
-    for non-``psum`` collectives."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_CHECK_KWARG: check})
+    """``jax.shard_map`` with the replication check (``check_vma``) off
+    by default: the sharded engine's per-chunk monoid combines
+    (``pmin``/``pmax``/delta-``psum``) produce values that are replicated
+    *by construction* — identical collectives on identical operands —
+    which the checker cannot always prove for non-``psum`` collectives.
+    Replicated operands and results of any rank take ``P()``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)

@@ -45,10 +45,12 @@ import argparse
 import dataclasses
 import json
 import os
+import re
 import time
 import zlib
 from typing import Optional
 
+import jax
 import numpy as np
 
 from repro.core.graph import CSRGraph
@@ -187,10 +189,15 @@ class CostModel:
 
 def graph_signature(graph: CSRGraph, backend: str,
                     sched: Schedule = DEFAULT_SCHEDULE) -> dict:
-    """What a calibration is valid for: topology shape + backend +
-    schedule + protocol version.  Weights and exact wiring do not enter —
-    the step kernels' cost is shape-driven."""
+    """What a calibration is valid for: the device it was timed on
+    (platform and kind, as JAX reports them) + topology shape + backend
+    + schedule + protocol version.  Weights and exact wiring do not
+    enter — the step kernels' cost is shape-driven; the device does, so
+    a CPU calibration is never loaded on a TPU."""
+    dev = jax.devices()[0]
     return {
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
         "n": int(graph.num_nodes),
         "e": int(graph.num_edges),
         "max_degree": int(graph.max_degree),
@@ -204,8 +211,10 @@ def cache_path(cache_dir: str, sig: dict) -> str:
     # zlib.crc32, not hash(): str hashes are salted per process, and the
     # whole point of the cache is cross-process reuse
     sched_key = zlib.crc32(sig["schedule"].encode())
-    key = (f"{sig['n']}n-{sig['e']}e-{sig['max_degree']}d-"
-           f"{sig['backend']}-{sched_key:08x}-v{sig['version']}")
+    kind = re.sub(r"[^A-Za-z0-9]+", "-", sig["device_kind"]).strip("-")
+    key = (f"{sig['platform']}-{kind}-{sig['n']}n-{sig['e']}e-"
+           f"{sig['max_degree']}d-{sig['backend']}-{sched_key:08x}-"
+           f"v{sig['version']}")
     return os.path.join(cache_dir, f"costmodel-{key}.json")
 
 

@@ -41,7 +41,7 @@ from repro.core.schedule import Schedule
 from repro.core.strategies import (
     BACKENDS, EdgeBased, FRONTIER_INIT, IterStats, NodeSplitting,
     PALLAS_BACKEND, PRIORITY_SCHEDULE, SHARDABLE, StrategyBase,
-    make_strategy)  # noqa: F401  (make_strategy re-exported: engine.make_strategy)
+    make_strategy, pallas_relax)  # noqa: F401  (make_strategy re-exported: engine.make_strategy)
 
 #: work-ordering schedules engine.run/fixed_point/run_batch accept:
 #: "bsp" relaxes the whole frontier every iteration (bulk-synchronous,
@@ -177,6 +177,31 @@ def _check_backend(strategy: Optional[StrategyBase], backend: str,
             f"strategy {strategy.name!r} does not declare the "
             f"{PALLAS_BACKEND!r} capability; its kernels have no Pallas "
             f"lowering — use backend='xla' (docs/backends.md)")
+
+
+#: relax kernels each fused lowering dispatches under backend="pallas"
+#: (repro.kernels.relax: "lanes" = relax_lanes, "wd" = wd_relax_lanes)
+_PALLAS_KERNELS = {"BS": ("lanes",), "NS": ("lanes",), "EP": ("lanes",),
+                   "WD": ("wd",), "HP": ("lanes", "wd"),
+                   "AD": ("lanes", "wd")}
+
+
+def _check_pallas_fit(strategy: StrategyBase, n_alloc: int, num_edges: int,
+                      splan=None) -> None:
+    """Refuse ``backend="pallas"`` with a ``ValueError`` naming the bytes
+    when the strategy's kernels cannot keep this graph's tables in VMEM
+    (:func:`repro.kernels.relax.check_vmem`) — before anything compiles.
+    Sharded runs are checked per shard: the replicated ``[N]`` values
+    plus the widest shard's slot and edge tables."""
+    kernels = _PALLAS_KERNELS.get(_fused.fused_kernel_name(type(strategy)),
+                                  ("lanes", "wd"))
+    f, e = n_alloc, num_edges
+    if splan is not None:
+        f = splan.sharded.nodes_per_shard
+        e = splan.sharded.edges_per_shard
+    sched = _fused._sched_of(strategy)
+    pallas_relax.check_vmem(kernels, n=n_alloc, f=f, e=e,
+                            **pallas_relax.tile_kwargs(sched))
 
 
 def _check_schedule(strategy: Optional[StrategyBase], schedule: str,
@@ -327,6 +352,8 @@ def run(graph: CSRGraph, source: int, strategy: StrategyBase, *,
         n_alloc = strategy.split_info.graph.num_nodes
     else:
         n_alloc = graph.num_nodes
+    if backend == "pallas":
+        _check_pallas_fit(strategy, n_alloc, graph.num_edges, splan)
 
     dist = (jnp.full((n_alloc,), op.identity, op.dtype)
             .at[source].set(op.seed(source)))
@@ -500,11 +527,15 @@ def fixed_point(graph: CSRGraph, strategy: StrategyBase, init, *,
         n_alloc = strategy.split_info.graph.num_nodes
     else:
         n_alloc = graph.num_nodes
-    dist, mask = init(n_alloc)
-
+    splan = None
     if shards is not None:
         splan = _shard.plan_shards(strategy, state, graph, shards,
                                    method=partition)
+    if backend == "pallas":
+        _check_pallas_fit(strategy, n_alloc, graph.num_edges, splan)
+    dist, mask = init(n_alloc)
+
+    if splan is not None:
         dist, it, edges, _rounds = _shard.run_fixed_point(
             splan, dist, mask, op=op, max_iterations=max_iterations,
             async_mode=async_shards, backend=backend)
@@ -555,7 +586,7 @@ def run_batch(graph: CSRGraph, sources, *, max_iterations: int = 100000,
     Thin wrapper over :func:`repro.core.multi_source.run_batch`; kept here
     so single-source and batched entry points live side by side.
     ``shards=S`` (fused mode only) shards the graph over S devices and
-    vmaps the sharded WD step over the source axis (docs/sharding.md);
+    maps the sharded WD step over the source axis (docs/sharding.md);
     ``backend="pallas"`` swaps the relax lowering, sharded or not
     (docs/backends.md); ``schedule="delta"`` (fused mode only) vmaps
     whole per-row delta-stepping traversals (docs/scheduling.md);

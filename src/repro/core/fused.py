@@ -92,7 +92,8 @@ from repro.core.schedule import DEFAULT_SCHEDULE, Schedule
 from repro.core.strategies import (
     AdaptiveStrategy, EdgeBased, HierarchicalProcessing, NodeBased,
     NodeSplitting, WorkloadDecomposition, _apply_relax, _edge_weight,
-    pallas_relax_module, relax_fn)
+    _merge_path_lanes, pallas_relax, relax_fn)
+from repro.core.worklist import prefix_sum
 
 #: traversals started, per kernel — incremented once per fused fixed-point
 #: call on the host side.  ``DISPATCH_COUNTS[k]`` growing by exactly 1 per
@@ -128,59 +129,141 @@ def _limb_add(hi, lo, e):
     return hi + e_hi + lo // _LIMB, lo % _LIMB
 
 
+#: lane-block sizes of one relax batch in the XLA lowering.  A batch
+#: runs in the smallest block that holds it, so an iteration costs what
+#: its frontier relaxes, not the graph's size; a batch past the largest
+#: block runs as a loop of largest blocks (see :func:`_relax_batch`).
+_BLOCK_SIZES = (1 << 8, 1 << 12, 1 << 16, 1 << 20)
+
+
+def _block_sizes(cap: int, backend: str) -> tuple:
+    """Static block sizes for batches of at most ``cap`` lanes, ascending.
+    The last size holds every batch, except in the XLA lowering of a
+    batch that can exceed :data:`_BLOCK_SIZES`' largest.  Pallas batches
+    run as one block of the full width: its VMEM check already bounds
+    the graph, and one width is one kernel to compile."""
+    top = 1 << (max(int(cap), 1) - 1).bit_length()
+    if backend != "xla":
+        return (top,)
+    sizes = tuple(s for s in _BLOCK_SIZES if s < top)
+    return sizes + (top,) if top <= _BLOCK_SIZES[-1] else sizes
+
+
+def _snapshot_relax(snap, dist, updated, src, dst, w, valid, *,
+                    op: EdgeOp):
+    """One block of a multi-block batch: candidates and the improve test
+    read ``snap``, the values at the batch's start, and the improving
+    candidates fold into ``dist`` — so the blocks together relax exactly
+    as one ``_apply_relax`` over all their lanes would."""
+    src_c = jnp.clip(src, 0, snap.shape[0] - 1)
+    dst_c = jnp.clip(dst, 0, snap.shape[0] - 1)
+    cand = op.message(snap[src_c], w)
+    improve = valid & op.improves(cand, snap[dst_c])
+    return (op.scatter(dist, dst_c, cand, improve),
+            updated.at[dst_c].max(improve))
+
+
+def _relax_batch(dist, updated, total, lanes, *, cap: int, op: EdgeOp,
+                 backend: str, sched: Schedule):
+    """Relax lanes ``[0, total)`` as ONE batch — the semantics of one
+    ``relax_fn`` call over all of them — in blocks sized to ``total``.
+
+    ``lanes(lo, size)`` returns the ``(src, dst, w)`` of lanes
+    ``lo .. lo+size-1``; lanes at or past ``total`` are masked.
+    ``cap`` bounds ``total`` statically.  The batch runs in the smallest
+    block of :func:`_block_sizes` that holds it (one ``lax.switch``
+    branch per size); past the largest, a loop of blocks reads from a
+    snapshot of the values at entry (:func:`_snapshot_relax`).  The set
+    of relaxed ``(src, dst, w)`` triples is the same either way, and the
+    monoid fold does not depend on lane order, so results are
+    bit-identical to one dense batch."""
+    relax = relax_fn(backend, sched)
+    sizes = _block_sizes(cap, backend)
+
+    def one_block(size):
+        def run(c):
+            src, dst, w = lanes(0, size)
+            valid = jnp.arange(size, dtype=jnp.int32) < total
+            dist, updated, _ = relax(c[0], c[1], src, dst, w, valid, op=op)
+            return dist, updated
+        return run
+
+    branches = [one_block(s) for s in sizes]
+    if max(int(cap), 1) > sizes[-1]:
+        size = sizes[-1]
+
+        def loop(c):
+            snap = c[0]
+
+            def body(b, c):
+                lo = b * size
+                src, dst, w = lanes(lo, size)
+                valid = lo + jnp.arange(size, dtype=jnp.int32) < total
+                return _snapshot_relax(snap, c[0], c[1], src, dst, w, valid,
+                                       op=op)
+            return lax.fori_loop(0, (total + size - 1) // size, body, c)
+        branches.append(loop)
+    if len(branches) == 1:
+        return branches[0]((dist, updated))
+    idx = jnp.sum((total > jnp.asarray(sizes, jnp.int32)).astype(jnp.int32))
+    return lax.switch(idx, branches, (dist, updated))
+
+
 def _merge_path_relax(g: CSRGraph, dist, updated, work, cursor=None, *,
                       op: EdgeOp = operators.shortest_path,
                       backend: str = "xla",
                       sched: Schedule = DEFAULT_SCHEDULE):
-    """One synchronous merge-path relax over ``E`` edge lanes.
+    """One synchronous merge-path relax over the ``work`` edges.
 
-    ``work[n]`` is how many edges node ``n`` contributes; each lane
-    binary-searches its (node, local-edge) pair in the prefix sum — the
-    on-device replacement for host compaction.  ``cursor`` (optional)
-    offsets every node's read position into its adjacency list (the HP
-    tail).  Returns ``(dist, updated, total_work)``.
+    ``work[n]`` is how many edges node ``n`` contributes; lane ``k``
+    finds its (node, local-edge) pair in the prefix sum — the on-device
+    replacement for host compaction — and the lanes relax as one batch
+    (:func:`_relax_batch`).  ``cursor`` (optional) offsets every node's
+    read position into its adjacency list (the HP tail).  Returns
+    ``(dist, updated, total_work)``.
 
     ``backend="pallas"`` fuses the search and the relax in one kernel
     (``repro.kernels.relax.wd_relax_lanes``) — the per-lane node index
     never materializes."""
-    prefix = jnp.cumsum(work)
+    prefix = prefix_sum(work)
     exclusive = prefix - work
     total = prefix[-1]
+    start = g.row_ptr[:-1] if cursor is None else g.row_ptr[:-1] + cursor
     if backend == "pallas":
-        relax = pallas_relax_module()
-        start = (g.row_ptr[:-1] if cursor is None
-                 else g.row_ptr[:-1] + cursor)
         src_ids = jnp.arange(g.num_nodes, dtype=jnp.int32)
-        prop, upd, _ = relax.wd_relax_lanes(
+        prop, upd, _ = pallas_relax.wd_relax_lanes(
             dist, prefix, exclusive, start, src_ids, g.col, g.wt,
-            cap_work=g.num_edges, op=op, **relax.tile_kwargs(sched))
-        return (relax.apply_proposal(dist, prop, op),
+            cap_work=g.num_edges, op=op, **pallas_relax.tile_kwargs(sched))
+        return (pallas_relax.apply_proposal(dist, prop, op),
                 updated | upd, total)
-    k = jnp.arange(g.num_edges, dtype=jnp.int32)
-    node = jnp.searchsorted(prefix, k, side="right").astype(jnp.int32)
-    node = jnp.clip(node, 0, g.num_nodes - 1)
-    local = k - exclusive[node]
-    start = g.row_ptr[node] if cursor is None else g.row_ptr[node] + cursor[node]
-    eidx = jnp.clip(start + local, 0, g.num_edges - 1)
-    valid = k < total
-    dist, updated, _ = _apply_relax(
-        dist, updated, node, g.col[eidx], _edge_weight(g, eidx), valid,
-        op=op)
+    lanes = _merge_path_lanes(work, prefix, start - exclusive, g.col, g.wt,
+                              num_edges=g.num_edges,
+                              pad=_block_sizes(g.num_edges, backend)[-1])
+    dist, updated = _relax_batch(dist, updated, total, lanes,
+                                 cap=g.num_edges, op=op, backend=backend,
+                                 sched=sched)
     return dist, updated, total
 
 
 def _bs_step(g: CSRGraph, dist, mask, *,
              op: EdgeOp = operators.shortest_path, backend: str = "xla",
              sched: Schedule = DEFAULT_SCHEDULE):
-    """Dense BS: every node lane walks its own adjacency list in lockstep.
+    """Dense BS: every frontier node walks its own adjacency list in
+    lockstep.
 
     Column ``d`` relaxes the ``d``-th edge of every frontier node — the
     same relax batches, in the same order, as ``bs_relax`` over a
-    compacted frontier, so intra-iteration propagation is identical."""
-    relax = relax_fn(backend, sched)
+    compacted frontier, so intra-iteration propagation is identical.
+    The frontier is ordered by degree once per iteration, so column
+    ``d``'s lanes are a prefix of that order and a column costs the
+    nodes still walking, not ``N`` (:func:`_relax_batch`)."""
     deg = _masked_degrees(g, mask)
+    n = g.num_nodes
+    pad = _block_sizes(n, backend)[-1]
+    order = jnp.argsort(deg, descending=True, stable=True).astype(jnp.int32)
+    walking = -deg[order]                   # ascending: -degree by rank
+    order = jnp.pad(order, (0, pad))
     base = g.row_ptr[:-1]
-    nodes = jnp.arange(g.num_nodes, dtype=jnp.int32)
     fmax = jnp.max(deg)
     updated = jnp.zeros_like(mask)
 
@@ -189,11 +272,15 @@ def _bs_step(g: CSRGraph, dist, mask, *,
 
     def body(c):
         d, dist, updated = c
-        valid = mask & (d < deg)
-        eidx = jnp.clip(base + d, 0, g.num_edges - 1)
-        dist, updated, _ = relax(
-            dist, updated, nodes, g.col[eidx], _edge_weight(g, eidx), valid,
-            op=op)
+
+        def lanes(lo, size):
+            src = lax.dynamic_slice(order, (lo,), (size,))
+            eidx = jnp.clip(base[src] + d, 0, g.num_edges - 1)
+            return src, g.col[eidx], _edge_weight(g, eidx)
+        # frontier nodes with degree > d walk column d
+        live = jnp.searchsorted(walking, -d, side="left").astype(jnp.int32)
+        dist, updated = _relax_batch(dist, updated, live, lanes, cap=n,
+                                     op=op, backend=backend, sched=sched)
         return d + 1, dist, updated
 
     _, dist, updated = lax.while_loop(cond, body,
@@ -228,11 +315,7 @@ def _hp_step(g: CSRGraph, dist, mask, *, sched: Schedule = DEFAULT_SCHEDULE,
     switch_threshold = sched.switch_threshold
     deg = _masked_degrees(g, mask)
     count = jnp.sum(mask.astype(jnp.int32))
-    n, e = g.num_nodes, g.num_edges
-    base = g.row_ptr[:-1]
-    nodes = jnp.arange(n, dtype=jnp.int32)
-
-    relax = relax_fn(backend, sched)
+    n = g.num_nodes
 
     def small(dist):
         dist, updated, _ = _wd_step(g, dist, mask, op=op, backend=backend,
@@ -240,8 +323,6 @@ def _hp_step(g: CSRGraph, dist, mask, *, sched: Schedule = DEFAULT_SCHEDULE,
         return dist, updated
 
     def big(dist):
-        j = jnp.arange(mdt, dtype=jnp.int32)[None, :]
-
         def live(cursor):
             return jnp.sum((mask & (cursor < deg)).astype(jnp.int32))
 
@@ -252,14 +333,14 @@ def _hp_step(g: CSRGraph, dist, mask, *, sched: Schedule = DEFAULT_SCHEDULE,
             return (i == 0) | (live(cursor) > switch_threshold)
 
         def body(c):
+            # one sub-iteration: the next <= MDT edges of every live node,
+            # one relax batch (the [N, MDT] tile's valid lanes)
             i, cursor, dist, updated = c
-            pos = cursor[:, None] + j                       # [N, mdt]
-            valid = mask[:, None] & (pos < deg[:, None])
-            eidx = jnp.clip(base[:, None] + pos, 0, e - 1).reshape(-1)
-            src = jnp.broadcast_to(nodes[:, None], (n, mdt)).reshape(-1)
-            dist, updated, _ = relax(
-                dist, updated, src, g.col[eidx], _edge_weight(g, eidx),
-                valid.reshape(-1), op=op)
+            tile = jnp.clip(deg - cursor, 0, mdt)
+            dist, updated, _ = _merge_path_relax(g, dist, updated, tile,
+                                                 cursor, op=op,
+                                                 backend=backend,
+                                                 sched=sched)
             return i + 1, cursor + mdt, dist, updated
 
         i0 = jnp.int32(0)
@@ -583,7 +664,7 @@ def _batch_fixed_point(g: CSRGraph, dist_b, mask_b, *,
                        sched: Schedule = DEFAULT_SCHEDULE):
     """All K queries to their fixed points in one dispatch.
 
-    The dense WD step vmapped over the source axis inside one while_loop
+    The dense WD step mapped over the source axis inside one while_loop
     — the fused counterpart of ``multi_source.batched_wd_relax``'s
     per-iteration dispatch.  Iterations count until *every* row's
     frontier is empty (the batch's fixed point), matching the stepped
@@ -596,10 +677,12 @@ def _batch_fixed_point(g: CSRGraph, dist_b, mask_b, *,
 
     def body(c):
         it, dist_b, mask_b, e_hi, e_lo = c
-        dist_b, mask_b, e = jax.vmap(
-            lambda d, m: _wd_step(g, d, m, op=op, backend=backend,
-                                  sched=sched))(
-            dist_b, mask_b)
+        # rows one after another (lax.map, not vmap): each row's batch
+        # picks its own lane-block size, and only that block runs
+        dist_b, mask_b, e = lax.map(
+            lambda dm: _wd_step(g, dm[0], dm[1], op=op, backend=backend,
+                                sched=sched),
+            (dist_b, mask_b))
         # fold the K per-row totals one _limb_add at a time (each row is
         # < 2^31, but even the per-row remainders could wrap a plain
         # int32 sum once K is large)

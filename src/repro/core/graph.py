@@ -91,12 +91,19 @@ class CSRGraph:
         src = np.asarray(src, np.int64)
         dst = np.asarray(dst, np.int64)
         if dedup:
+            # keep the first occurrence of every (src, dst) pair, in key
+            # order (what np.unique(return_index=True) gives, without
+            # its stable sort): sorted by src already
             key = src * num_nodes + dst
-            _, idx = np.unique(key, return_index=True)
+            order = np.argsort(key)
+            key = key[order]
+            starts = np.flatnonzero(np.diff(key, prepend=-1))
+            idx = (np.minimum.reduceat(order, starts) if len(starts)
+                   else starts)
             src, dst = src[idx], dst[idx]
             if wt is not None:
                 wt = np.asarray(wt)[idx]
-        if sort:
+        if sort and not dedup:
             order = np.argsort(src, kind="stable")
             src, dst = src[order], dst[order]
             if wt is not None:

@@ -172,7 +172,7 @@ def run_batch(graph: CSRGraph, sources, *, max_iterations: int = 100000,
     :class:`repro.core.operators.EdgeOp` (or registered name) as ``op``
     for other semantics.  ``mode="fused"`` runs the whole batch in one
     device dispatch (see module docstring); ``shards=S`` additionally
-    partitions the graph over S devices and vmaps the *sharded* WD step
+    partitions the graph over S devices and maps the *sharded* WD step
     over the source axis — bit-identical dist/iterations/edges to the
     single-device batch (:mod:`repro.core.shard`, docs/sharding.md).
     ``backend="pallas"`` routes every row's WD relax through the fused
@@ -266,8 +266,9 @@ def run_batch(graph: CSRGraph, sources, *, max_iterations: int = 100000,
 
     if shards is not None:
         from repro.core import shard
-        sharded, _info = shard.partition(graph, shards, method=partition)
         mesh = shard.shard_mesh(shards)
+        sharded, _info = shard.partition(graph, shards, method=partition,
+                                         mesh=mesh)
         dist_b, iterations, edges = shard.run_batch_fixed_point(
             sharded, dist_b, mask_b, mesh=mesh, op=op,
             max_iterations=max_iterations, sched=sched, backend=backend)

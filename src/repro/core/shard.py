@@ -24,7 +24,7 @@ adds a 1-D **node partition** on top of the fused engine:
   iteration, see below), so distances, iteration counts and edge totals
   are **bit-identical** to the single-device fused and stepped paths;
 * :func:`run_batch_fixed_point` is the multi-source counterpart: the
-  sharded WD step ``vmap``-ed over K sources inside one
+  sharded WD step mapped over K sources inside one
   ``lax.while_loop``, mirroring ``fused._batch_fixed_point``.
 
 Why combine-per-chunk and not once per iteration: inside one frontier
@@ -86,16 +86,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.compat import shard_map
 from repro.core import operators
-from repro.core.fused import (DISPATCH_COUNTS, TRACE_COUNTS, _count_key,
-                              _limb_add, _LIMB, _plan)
+from repro.core.fused import (DISPATCH_COUNTS, TRACE_COUNTS, _block_sizes,
+                              _count_key, _limb_add, _LIMB, _plan,
+                              _relax_batch)
 from repro.core.graph import CSRGraph
 from repro.core.operators import EdgeOp
 from repro.core.schedule import DEFAULT_SCHEDULE, Schedule
-from repro.core.strategies import _apply_relax, pallas_relax_module
+from repro.core.strategies import _merge_path_lanes, pallas_relax
+from repro.core.worklist import prefix_sum
 
 #: mesh axis name of the 1-D shard partition
 AXIS = "shard"
@@ -234,11 +236,14 @@ def partition_boundaries(graph: CSRGraph, num_shards: int,
 
 
 def partition(graph: CSRGraph, num_shards: int, *,
-              method: str = "degree"
+              method: str = "degree", mesh=None
               ) -> tuple[ShardedCSRGraph, ShardInfo]:
     """Split ``graph`` into ``num_shards`` per-shard local CSRs (host-side
     numpy morph, like :mod:`repro.core.node_split`).  Returns the
-    stacked device representation plus host-side :class:`ShardInfo`."""
+    stacked device representation plus host-side :class:`ShardInfo`.
+    With ``mesh`` (a :func:`shard_mesh`), each shard's block goes
+    straight from the host to its own device; without, the stack lands
+    on the default device."""
     if num_shards < 1:
         raise ValueError(f"num_shards must be >= 1, got {num_shards}")
     bounds = partition_boundaries(graph, num_shards, method)
@@ -269,12 +274,16 @@ def partition(graph: CSRGraph, num_shards: int, *,
         cut[s] = int(crossing.sum())
         ghosts.append(np.unique(col[e0:e1][crossing]))
 
+    if mesh is None:
+        put = jnp.asarray
+    else:
+        put = partial(jax.device_put, device=NamedSharding(mesh, P(AXIS)))
     sharded = ShardedCSRGraph(
-        row_ptr=jnp.asarray(row_ptr_s),
-        col=jnp.asarray(col_s),
-        wt=None if wt_s is None else jnp.asarray(wt_s),
-        node_base=jnp.asarray(bounds[:-1], jnp.int32),
-        num_local=jnp.asarray(counts, jnp.int32),
+        row_ptr=put(row_ptr_s),
+        col=put(col_s),
+        wt=None if wt_s is None else put(wt_s),
+        node_base=put(bounds[:-1].astype(np.int32)),
+        num_local=put(counts.astype(np.int32)),
         num_nodes=graph.num_nodes,
         num_edges=graph.num_edges,
         num_shards=num_shards,
@@ -364,30 +373,20 @@ def _combine_proposal(op: EdgeOp, prop):
 
 
 def _relax_chunk(dist, updated, src, dst, w, valid, *, op: EdgeOp,
-                 backend: str, sched: Schedule, sync: bool):
-    """One direct-mapped relax batch + its chunk-boundary ghost combine,
-    dispatched per backend (the sharded analogue of
-    ``strategies.relax_fn``).
-
-    XLA scatters into the local replica and folds replicas
-    (:func:`_maybe_combine`); Pallas runs the fused
-    ``relax_lanes`` kernel and folds its dense proposal across shards
-    (:func:`_combine_proposal`) before one ``apply_proposal`` — the
-    fused-epilogue combine.  ``sync=False`` (async mode) skips the fold
-    either way: the relax commits to the local replica only."""
-    if backend == "pallas":
-        relax = pallas_relax_module()
-        hi = dist.shape[0] - 1
-        prop, upd, _ = relax.relax_lanes(
-            dist, jnp.clip(src, 0, hi), jnp.clip(dst, 0, hi), w, valid,
-            op=op, **relax.tile_kwargs(sched))
-        if sync:
-            prop = _combine_proposal(op, prop)
-        return relax.apply_proposal(dist, prop, op), updated | upd
-    base = dist
-    dist, updated, _ = _apply_relax(dist, updated, src, dst, w, valid,
-                                    op=op)
-    return _maybe_combine(op, base, dist, sync), updated
+                 sched: Schedule, sync: bool):
+    """One direct-mapped Pallas relax batch + its chunk-boundary ghost
+    combine: the fused ``relax_lanes`` kernel's dense proposal is folded
+    across shards (:func:`_combine_proposal`) before one
+    ``apply_proposal`` — the fused-epilogue combine.  ``sync=False``
+    (async mode) skips the fold: the relax commits to the local replica
+    only."""
+    hi = dist.shape[0] - 1
+    prop, upd, _ = pallas_relax.relax_lanes(
+        dist, jnp.clip(src, 0, hi), jnp.clip(dst, 0, hi), w, valid,
+        op=op, **pallas_relax.tile_kwargs(sched))
+    if sync:
+        prop = _combine_proposal(op, prop)
+    return pallas_relax.apply_proposal(dist, prop, op), updated | upd
 
 
 def _any_across(updated):
@@ -419,35 +418,32 @@ def _merge_path_local(sq: ShardedCSRGraph, dist, updated, gids, work,
     cross-shard combine — the sharded analogue of
     ``fused._merge_path_relax`` (single chunk, so one combine).
     ``backend="pallas"`` fuses the search and the relax in one
-    ``wd_relax_lanes`` kernel (the rank/eidx/valid construction inside
-    the kernel is the same searchsorted arithmetic as below, so lanes
-    resolve identically) and folds the proposal across shards in the
-    epilogue.  ``sync=False`` (async mode) skips the combine: the relax
-    commits to the local replica only."""
-    prefix = jnp.cumsum(work)
+    ``wd_relax_lanes`` kernel (its in-kernel rank search resolves every
+    lane to the same (node, edge) as ``_merge_path_lanes`` below) and
+    folds the proposal across shards in the epilogue.  ``sync=False``
+    (async mode) skips the combine: the relax commits to the local
+    replica only."""
+    prefix = prefix_sum(work)
+    exclusive = prefix - work
     total = prefix[-1]
+    start = sq.row_ptr[:-1] if cursor is None else sq.row_ptr[:-1] + cursor
     if backend == "pallas":
-        relax = pallas_relax_module()
-        start = (sq.row_ptr[:-1] if cursor is None
-                 else sq.row_ptr[:-1] + cursor)
-        prop, upd, _ = relax.wd_relax_lanes(
-            dist, prefix, prefix - work, start, gids, sq.col, sq.wt,
-            cap_work=sq.edges_per_shard, op=op, **relax.tile_kwargs(sched))
+        prop, upd, _ = pallas_relax.wd_relax_lanes(
+            dist, prefix, exclusive, start, gids, sq.col, sq.wt,
+            cap_work=sq.edges_per_shard, op=op,
+            **pallas_relax.tile_kwargs(sched))
         if sync:
             prop = _combine_proposal(op, prop)
-        return relax.apply_proposal(dist, prop, op), updated | upd, total
-    exclusive = prefix - work
-    k = jnp.arange(sq.edges_per_shard, dtype=jnp.int32)
-    ni = jnp.clip(jnp.searchsorted(prefix, k, side="right").astype(jnp.int32),
-                  0, work.shape[0] - 1)
-    local = k - exclusive[ni]
-    start = sq.row_ptr[ni] if cursor is None else sq.row_ptr[ni] + cursor[ni]
-    eidx = jnp.clip(start + local, 0, sq.edges_per_shard - 1)
-    valid = k < total
+        return (pallas_relax.apply_proposal(dist, prop, op), updated | upd,
+                total)
+    lanes = _merge_path_lanes(
+        work, prefix, start - exclusive, sq.col, sq.wt,
+        num_edges=sq.edges_per_shard,
+        pad=_block_sizes(sq.edges_per_shard, backend)[-1], src_ids=gids)
     base = dist
-    dist, updated, _ = _apply_relax(
-        dist, updated, gids[ni], sq.col[eidx], _local_weight(sq, eidx),
-        valid, op=op)
+    dist, updated = _relax_batch(dist, updated, total, lanes,
+                                 cap=sq.edges_per_shard, op=op,
+                                 backend=backend, sched=sched)
     return _maybe_combine(op, base, dist, sync), updated, total
 
 
@@ -460,22 +456,44 @@ def _bs_step(sq: ShardedCSRGraph, dist, mask, *, op: EdgeOp,
     the combine runs per column — the chunk boundary at which the
     single-device ``_bs_step`` lets values chain.  ``sync=False`` walks
     only the *local* max degree and never combines (async mode — no
-    collectives, shard-dependent trip counts allowed)."""
+    collectives, shard-dependent trip counts allowed).
+
+    In the XLA lowering the owned lanes are ordered by degree once per
+    iteration, as in ``fused._bs_step``, so column ``d`` relaxes only
+    the lanes still walking, in a lane block sized to them
+    (``_relax_batch``): past the first columns of a skewed frontier a
+    column costs its combine, not ``Nmax`` lanes."""
     gids, deg, _ = _local_frontier(sq, mask)
     fmax = lax.pmax(jnp.max(deg), AXIS) if sync else jnp.max(deg)
     updated = jnp.zeros_like(mask)
+    n_lanes = deg.shape[0]
+    base = sq.row_ptr[:-1]
+    order = jnp.argsort(deg, descending=True, stable=True).astype(jnp.int32)
+    walking = -deg[order]                   # ascending: -degree by rank
+    order = jnp.pad(order, (0, _block_sizes(n_lanes, backend)[-1]))
 
     def cond(c):
         return c[0] < fmax
 
     def body(c):
         d, dist, updated = c
-        valid = d < deg
-        eidx = jnp.clip(sq.row_ptr[:-1] + d, 0, sq.edges_per_shard - 1)
-        dist, updated = _relax_chunk(
-            dist, updated, gids, sq.col[eidx], _local_weight(sq, eidx),
-            valid, op=op, backend=backend, sched=sched, sync=sync)
-        return d + 1, dist, updated
+        if backend == "pallas":
+            eidx = jnp.clip(base + d, 0, sq.edges_per_shard - 1)
+            dist, updated = _relax_chunk(
+                dist, updated, gids, sq.col[eidx], _local_weight(sq, eidx),
+                d < deg, op=op, sched=sched, sync=sync)
+            return d + 1, dist, updated
+
+        def lanes(lo, size):
+            lane = lax.dynamic_slice(order, (lo,), (size,))
+            eidx = jnp.clip(base[lane] + d, 0, sq.edges_per_shard - 1)
+            return gids[lane], sq.col[eidx], _local_weight(sq, eidx)
+        # owned lanes with degree > d walk column d
+        live = jnp.searchsorted(walking, -d, side="left").astype(jnp.int32)
+        before = dist
+        dist, updated = _relax_batch(dist, updated, live, lanes, cap=n_lanes,
+                                     op=op, backend=backend, sched=sched)
+        return d + 1, _maybe_combine(op, before, dist, sync), updated
 
     _, dist, updated = lax.while_loop(cond, body,
                                       (jnp.int32(0), dist, updated))
@@ -520,7 +538,6 @@ def _hp_step(sq: ShardedCSRGraph, dist, mask, *,
 
     def big(dist):
         n_lanes = sq.row_ptr.shape[0] - 1
-        j = jnp.arange(mdt, dtype=jnp.int32)[None, :]
 
         def live(cursor):
             alive = jnp.sum((cursor < deg).astype(jnp.int32))
@@ -533,17 +550,13 @@ def _hp_step(sq: ShardedCSRGraph, dist, mask, *,
             return (i == 0) | (live(cursor) > switch_threshold)
 
         def body(c):
+            # one sub-iteration (the next <= MDT edges of every live
+            # owned node) = one merge-path batch + its combine
             i, cursor, dist, updated = c
-            pos = cursor[:, None] + j                       # [Nmax, mdt]
-            valid = pos < deg[:, None]
-            eidx = jnp.clip(sq.row_ptr[:-1][:, None] + pos,
-                            0, sq.edges_per_shard - 1).reshape(-1)
-            src = jnp.broadcast_to(gids[:, None],
-                                   (n_lanes, mdt)).reshape(-1)
-            dist, updated = _relax_chunk(
-                dist, updated, src, sq.col[eidx], _local_weight(sq, eidx),
-                valid.reshape(-1), op=op, backend=backend, sched=sched,
-                sync=sync)
+            tile = jnp.clip(deg - cursor, 0, mdt)
+            dist, updated, _ = _merge_path_local(
+                sq, dist, updated, gids, tile, cursor, op=op,
+                backend=backend, sched=sched, sync=sync)
             return i + 1, cursor + mdt, dist, updated
 
         cursor0 = jnp.zeros((n_lanes,), jnp.int32)
@@ -636,8 +649,8 @@ def _sharded_fixed_point(sg: ShardedCSRGraph, aux, dist0, mask0, *,
 
     return shard_map(
         body, mesh=mesh,
-        in_specs=(P(AXIS), P(None), P(None), P(None)),
-        out_specs=(P(None), P(None), P(None), P(None)))(
+        in_specs=(P(AXIS), P(), P(), P()),
+        out_specs=(P(), P(), P(), P()))(
         sg, aux, dist0, mask0)
 
 
@@ -733,8 +746,8 @@ def _async_sharded_fixed_point(sg: ShardedCSRGraph, aux, dist0, mask0, *,
 
     return shard_map(
         body, mesh=mesh,
-        in_specs=(P(AXIS), P(None), P(None), P(None)),
-        out_specs=(P(None), P(None), P(None), P(None), P(None)))(
+        in_specs=(P(AXIS), P(), P(), P()),
+        out_specs=(P(), P(), P(), P(), P()))(
         sg, aux, dist0, mask0)
 
 
@@ -765,9 +778,10 @@ def plan_shards(strategy, state, graph: CSRGraph, num_shards: int, *,
             f"shardable kernels: {SHARDED_KERNELS} (EP's COO worklist "
             f"and AD's global frontier statistics stay single-device — "
             f"docs/sharding.md)")
-    sharded, info = partition(plan.graph, num_shards, method=method)
     if mesh is None:
         mesh = shard_mesh(num_shards)
+    sharded, info = partition(plan.graph, num_shards, method=method,
+                              mesh=mesh)
     return ShardedPlan(plan.kernel, sharded, info, plan.aux, plan.static,
                        mesh)
 
@@ -819,10 +833,12 @@ def _sharded_batch_fixed_point(sg: ShardedCSRGraph, dist_b, mask_b, *,
                                sched: Schedule = DEFAULT_SCHEDULE,
                                backend: str = "xla"):
     """All K sources to their fixed points, sharded: the sharded WD step
-    vmapped over the source axis inside one ``lax.while_loop`` — the
-    multi-device counterpart of ``fused._batch_fixed_point`` (the
-    per-row edge totals are already global after the in-``vmap``
-    ``psum``, so the limb fold matches it bit-for-bit).  ``backend``
+    mapped over the source axis inside one ``lax.while_loop`` — the
+    multi-device counterpart of ``fused._batch_fixed_point``, rows one
+    after another (``lax.map``) for the same reason: each row's relax
+    batch runs only its own lane-block branch, where ``vmap`` would run
+    every branch.  The per-row edge totals are already global after the
+    per-row ``psum``, so the limb fold matches it bit-for-bit.  ``backend``
     swaps the per-shard relax lowering exactly as in
     :func:`_sharded_fixed_point`."""
     TRACE_COUNTS[f"shard:{_count_key('batch', backend)}"] += 1
@@ -837,12 +853,12 @@ def _sharded_batch_fixed_point(sg: ShardedCSRGraph, dist_b, mask_b, *,
         def loop_body(c):
             it, dist_b, mask_b, e_hi, e_lo = c
 
-            def one(dist, mask):
-                dist, upd, e = _wd_step(sq, dist, mask, op=op,
+            def one(dm):
+                dist, upd, e = _wd_step(sq, dm[0], dm[1], op=op,
                                         backend=backend, sched=sched)
                 return dist, _any_across(upd), lax.psum(e, AXIS)
 
-            dist_b, mask_b, e = jax.vmap(one)(dist_b, mask_b)
+            dist_b, mask_b, e = lax.map(one, (dist_b, mask_b))
             e_hi, e_lo = lax.fori_loop(
                 0, e.shape[0],
                 lambda i, c: _limb_add(c[0], c[1], e[i]),
@@ -856,8 +872,8 @@ def _sharded_batch_fixed_point(sg: ShardedCSRGraph, dist_b, mask_b, *,
 
     return shard_map(
         body, mesh=mesh,
-        in_specs=(P(AXIS), P(None), P(None)),
-        out_specs=(P(None), P(None), P(None), P(None)))(sg, dist_b, mask_b)
+        in_specs=(P(AXIS), P(), P()),
+        out_specs=(P(), P(), P(), P()))(sg, dist_b, mask_b)
 
 
 def run_batch_fixed_point(sharded: ShardedCSRGraph, dist_b, mask_b, *,

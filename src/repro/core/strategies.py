@@ -75,12 +75,8 @@ from repro.core.graph import CSRGraph, COOGraph
 from repro.core.operators import EdgeOp
 from repro.core.schedule import (
     DEFAULT_SCHEDULE, Schedule, default_schedule, resolve_overrides)
-from repro.core.worklist import bucket, compact_mask, run_fill
-
-try:  # optional Pallas relax backend (backend="pallas", docs/backends.md)
-    from repro.kernels import relax as _pallas_relax
-except Exception:  # pragma: no cover - kernels are optional at import time
-    _pallas_relax = None
+from repro.core.worklist import bucket, compact_mask, prefix_sum, run_fill
+from repro.kernels import relax as pallas_relax
 
 
 #: execution backends of the relax kernels.  "xla" is the plain
@@ -118,18 +114,6 @@ def _apply_relax(dist, updated, src, dst, w, valid, *,
     return dist, updated, improve
 
 
-def pallas_relax_module():
-    """The :mod:`repro.kernels.relax` module, or a ``RuntimeError`` when
-    the optional Pallas import failed — the single availability check
-    every ``backend="pallas"`` code path (here and in
-    :mod:`repro.core.fused`) goes through."""
-    if _pallas_relax is None:  # pragma: no cover - import-time guard
-        raise RuntimeError(
-            "backend='pallas' needs repro.kernels.relax (Pallas "
-            "failed to import)")
-    return _pallas_relax
-
-
 def relax_fn(backend: str, sched: Schedule = DEFAULT_SCHEDULE):
     """The relax primitive for a backend: :func:`_apply_relax` (XLA
     gather/scatter) or the signature-compatible Pallas drop-in
@@ -142,8 +126,8 @@ def relax_fn(backend: str, sched: Schedule = DEFAULT_SCHEDULE):
     if backend == "xla":
         return _apply_relax
     if backend == "pallas":
-        mod = pallas_relax_module()
-        return partial(mod.apply_relax, **mod.tile_kwargs(sched))
+        return partial(pallas_relax.apply_relax,
+                       **pallas_relax.tile_kwargs(sched))
     raise ValueError(f"backend must be one of {BACKENDS}, got {backend!r}")
 
 
@@ -234,6 +218,67 @@ def ep_push_unchunked(row_ptr, improve, dst, total, *, cap_out: int):
 # WD — workload decomposition (merge-path over the frontier's edges)
 # ---------------------------------------------------------------------------
 
+def _merge_path_lanes(work, prefix, base, col, wt, *, num_edges: int,
+                      pad: int, src_ids=None):
+    """``lanes(lo, size) -> (src, dst, w)`` of merge-path lanes
+    ``lo .. lo+size-1`` — the XLA lowering of the paper's
+    ``find_offsets`` search.
+
+    Lane ``k`` belongs to node ``n`` when ``prefix[n-1] <= k <
+    prefix[n]`` (``prefix`` is the inclusive scan of ``work``) and reads
+    edge ``base[n] + k``.  Nodes with work are first compacted to slots,
+    each holding at least one lane; a block of ``size`` lanes then spans
+    at most ``size`` slots from the first one it touches, so ranking the
+    block takes a scatter + scan over ``size`` entries instead of a
+    binary search per lane.  ``src_ids`` maps node -> source id (default:
+    the node itself); ``pad`` is the largest ``size`` asked for."""
+    n = work.shape[0]
+    big = jnp.iinfo(jnp.int32).max
+    live = work > 0
+    slot = jnp.where(live, prefix_sum(live.astype(jnp.int32)) - 1, n + pad)
+    node_of = jnp.zeros((n,), jnp.int32).at[slot].set(
+        jnp.arange(n, dtype=jnp.int32), mode="drop")
+    slot_end = jnp.full((n + pad,), big, jnp.int32).at[slot].set(
+        prefix, mode="drop")
+
+    def lanes(lo, size):
+        first = jnp.searchsorted(slot_end[:n], lo, side="right")
+        ends = jax.lax.dynamic_slice(slot_end, (first,), (size,)) - lo
+        counts = jnp.zeros((size,), jnp.int32).at[
+            jnp.where(ends < size, ends, size)].add(1, mode="drop")
+        s = jnp.minimum(first + prefix_sum(counts), n - 1)
+        node = node_of[s]
+        k = lo + jnp.arange(size, dtype=jnp.int32)
+        eidx = jnp.clip(base[node] + k, 0, num_edges - 1)
+        w = (wt[eidx] if wt is not None
+             else jnp.ones((size,), jnp.int32))
+        return (node if src_ids is None else src_ids[node]), col[eidx], w
+    return lanes
+
+
+def _merge_relax(g: CSRGraph, dist, f, start, work, *, cap_work: int,
+                 op: EdgeOp, backend: str, sched: Schedule):
+    """Relax ``work[i]`` edges of frontier slot ``i`` (node ``f[i]``,
+    from edge ``start[i]`` on) over ``cap_work`` lanes, as one batch
+    (lanes ranked by :func:`_merge_path_lanes`)."""
+    prefix = prefix_sum(work)
+    exclusive = prefix - work
+    total = prefix[-1]
+    updated = jnp.zeros((dist.shape[0],), jnp.bool_)
+    if backend == "pallas":
+        prop, upd, _ = pallas_relax.wd_relax_lanes(
+            dist, prefix, exclusive, start, f, g.col, g.wt,
+            cap_work=cap_work, op=op, **pallas_relax.tile_kwargs(sched))
+        return pallas_relax.apply_proposal(dist, prop, op), updated | upd
+    src, dst, w = _merge_path_lanes(
+        work, prefix, start - exclusive, g.col, g.wt,
+        num_edges=g.num_edges, pad=cap_work, src_ids=f)(0, cap_work)
+    dist, updated, _ = _apply_relax(
+        dist, updated, src, dst, w,
+        jnp.arange(cap_work, dtype=jnp.int32) < total, op=op)
+    return dist, updated
+
+
 @partial(jax.jit, static_argnames=("cap_work", "op", "backend", "sched"))
 def wd_relax(g: CSRGraph, dist, frontier, cursor, *, cap_work: int,
              op: EdgeOp = operators.shortest_path, backend: str = "xla",
@@ -241,9 +286,9 @@ def wd_relax(g: CSRGraph, dist, frontier, cursor, *, cap_work: int,
     """Block-distribute the frontier's edges across ``cap_work`` lanes.
 
     prefix-sum over (remaining) frontier degrees, then every work item k
-    locates its (node, local edge) via binary search — the vectorized
-    equivalent of the paper's ``find_offsets`` + per-thread while-walk
-    (Fig. 4), with no serialization.
+    locates its (node, local edge) in it — the vectorized equivalent of
+    the paper's ``find_offsets`` + per-thread while-walk (Fig. 4), with
+    no serialization (:func:`_merge_relax`).
 
     ``backend="pallas"`` routes through
     :func:`repro.kernels.relax.wd_relax_lanes`, which fuses the
@@ -253,30 +298,9 @@ def wd_relax(g: CSRGraph, dist, frontier, cursor, *, cap_work: int,
     mask = frontier >= 0
     f = jnp.where(mask, frontier, 0)
     deg = jnp.where(mask, g.row_ptr[f + 1] - g.row_ptr[f] - cursor, 0)
-    deg = jnp.maximum(deg, 0)
-    prefix = jnp.cumsum(deg)
-    exclusive = prefix - deg
-    total = prefix[-1]
-    updated = jnp.zeros((dist.shape[0],), jnp.bool_)
-    if backend == "pallas":
-        relax = pallas_relax_module()
-        start = g.row_ptr[f] + cursor
-        prop, upd, _ = relax.wd_relax_lanes(
-            dist, prefix, exclusive, start, f, g.col, g.wt,
-            cap_work=cap_work, op=op, **relax.tile_kwargs(sched))
-        return relax.apply_proposal(dist, prop, op), updated | upd
-    k = jnp.arange(cap_work, dtype=jnp.int32)
-    node_idx = jnp.searchsorted(prefix, k, side="right").astype(jnp.int32)
-    node_idx = jnp.clip(node_idx, 0, frontier.shape[0] - 1)
-    src = f[node_idx]
-    local = k - exclusive[node_idx]
-    eidx = jnp.clip(g.row_ptr[src] + cursor[node_idx] + local,
-                    0, g.num_edges - 1)
-    valid = k < total
-    dist, updated, _ = _apply_relax(
-        dist, updated, src, g.col[eidx], _edge_weight(g, eidx), valid,
-        op=op)
-    return dist, updated
+    return _merge_relax(g, dist, f, g.row_ptr[f] + cursor,
+                        jnp.maximum(deg, 0), cap_work=cap_work, op=op,
+                        backend=backend, sched=sched)
 
 
 # ---------------------------------------------------------------------------
@@ -305,30 +329,29 @@ def ns_activate(dist2, mask2, child_parent):
 # HP — hierarchical processing (≤ MDT edges per node per sub-iteration)
 # ---------------------------------------------------------------------------
 
-@partial(jax.jit, static_argnames=("cap", "mdt", "op", "backend", "sched"))
-def hp_sub_relax(g: CSRGraph, dist, sub, cursor, *, cap: int, mdt: int,
+@partial(jax.jit, static_argnames=("cap_work", "mdt", "op", "backend",
+                                   "sched"))
+def hp_sub_relax(g: CSRGraph, dist, sub, cursor, *, cap_work: int, mdt: int,
                  op: EdgeOp = operators.shortest_path,
                  backend: str = "xla",
                  sched: Schedule = DEFAULT_SCHEDULE):
     """One sub-iteration: every sublist node processes its next ≤MDT edges
-    (a dense [cap, MDT] tile — all lanes bounded by MDT, i.e. balanced
-    within the threshold, §III-C).  Returns the surviving sublist mask."""
-    del cap
+    (the valid lanes of a [sublist, MDT] tile — all bounded by MDT, i.e.
+    balanced within the threshold, §III-C), laid out over ``cap_work``
+    lanes by the merge path.  Returns the surviving sublist mask and the
+    next sub-iteration's lane count."""
     mask = sub >= 0
     n = jnp.where(mask, sub, 0)
     deg = g.row_ptr[n + 1] - g.row_ptr[n]
-    j = jnp.arange(mdt, dtype=jnp.int32)[None, :]
-    pos = cursor[:, None] + j
-    valid = mask[:, None] & (pos < deg[:, None])
-    eidx = jnp.clip(g.row_ptr[n][:, None] + pos, 0, g.num_edges - 1)
-    src = jnp.broadcast_to(n[:, None], eidx.shape).reshape(-1)
-    updated = jnp.zeros((dist.shape[0],), jnp.bool_)
-    dist, updated, _ = relax_fn(backend, sched)(
-        dist, updated, src, g.col[eidx.reshape(-1)],
-        _edge_weight(g, eidx.reshape(-1)), valid.reshape(-1), op=op)
+    tile = jnp.where(mask, jnp.clip(deg - cursor, 0, mdt), 0)
+    dist, updated = _merge_relax(g, dist, n, g.row_ptr[n] + cursor, tile,
+                                 cap_work=cap_work, op=op, backend=backend,
+                                 sched=sched)
     new_cursor = cursor + mdt
     alive = mask & (new_cursor < deg)
-    return dist, updated, new_cursor, alive
+    next_work = jnp.sum(jnp.where(alive,
+                                  jnp.clip(deg - new_cursor, 0, mdt), 0))
+    return dist, updated, new_cursor, alive, next_work
 
 
 @partial(jax.jit, static_argnames=("cap_out",))
@@ -705,12 +728,16 @@ class HierarchicalProcessing(StrategyBase):
         sub, cursor = frontier, jnp.zeros((cap,), jnp.int32)
         live = count
         subiters = 0
+        f_np = np.asarray(frontier)
+        work = int(np.minimum(self._degrees[f_np[f_np >= 0]], mdt).sum())
         while live > sched.switch_threshold:
-            dist, upd, cursor, alive = hp_sub_relax(
-                g, dist, sub, cursor, cap=sub.shape[0], mdt=mdt, op=op,
+            dist, upd, cursor, alive, work = hp_sub_relax(
+                g, dist, sub, cursor,
+                cap_work=bucket(work, sched.min_bucket), mdt=mdt, op=op,
                 backend=backend, sched=sched)
             acc_mask = acc_mask | upd
             live = int(jnp.sum(alive))
+            work = int(work)
             subiters += 1
             if live:
                 cap2 = bucket(live, sched.min_bucket)

@@ -25,25 +25,40 @@ def _finish(src, dst, num_nodes, weighted, seed, dedup=True) -> CSRGraph:
     return CSRGraph.from_edges(src, dst, wt, num_nodes, dedup=dedup)
 
 
+#: edges per draw of the chunked generator below: large enough to
+#: amortise numpy call overhead, small enough to stay cache-resident
+_GEN_CHUNK = 1 << 20
+
+
 def _rmat_edges(scale: int, edge_factor: int, a: float, b: float, c: float,
                 seed: int) -> tuple[np.ndarray, np.ndarray]:
-    """Recursive-matrix edge generation (Chakrabarti et al.), vectorized."""
+    """Recursive-matrix edge generation (Chakrabarti et al.), vectorized.
+
+    Bit by bit, one uniform draw per edge picks the source half and a
+    second the destination half.  The draws are made in cache-sized
+    chunks in the same order as one ``rng.random(m)`` per pass, so the
+    graph for a seed does not depend on the chunk size."""
     n = 1 << scale
     m = n * edge_factor
     rng = np.random.default_rng(seed)
-    src = np.zeros(m, np.int64)
-    dst = np.zeros(m, np.int64)
+    src = np.zeros(m, np.int32)
+    dst = np.zeros(m, np.int32)
     ab = a + b
     c_norm = c / (1.0 - ab) if ab < 1.0 else 0.0
     a_norm = a / ab if ab > 0 else 0.0
+    upper = np.empty(m, np.bool_)      # this bit's source is in the upper half
+    buf = np.empty(min(m, _GEN_CHUNK))
     for bit in range(scale):
-        r1 = rng.random(m)
-        r2 = rng.random(m)
-        src_bit = (r1 > ab).astype(np.int64)
-        dst_bit = ((r1 > ab) & (r2 > c_norm)
-                   | (r1 <= ab) & (r2 > a_norm)).astype(np.int64)
-        src |= src_bit << bit
-        dst |= dst_bit << bit
+        for lo in range(0, m, _GEN_CHUNK):
+            hi = min(lo + _GEN_CHUNK, m)
+            r1 = rng.random(hi - lo, out=buf[:hi - lo])
+            np.greater(r1, ab, out=upper[lo:hi])
+            src[lo:hi] |= upper[lo:hi].astype(np.int32) << bit
+        for lo in range(0, m, _GEN_CHUNK):
+            hi = min(lo + _GEN_CHUNK, m)
+            r2 = rng.random(hi - lo, out=buf[:hi - lo])
+            thr = np.where(upper[lo:hi], c_norm, a_norm)
+            dst[lo:hi] |= (r2 > thr).astype(np.int32) << bit
     # permute vertex labels so degree doesn't correlate with id
     perm = rng.permutation(n)
     return perm[src], perm[dst]

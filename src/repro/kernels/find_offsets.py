@@ -33,13 +33,16 @@ def _kernel(prefix_ref, out_ref, *, f_pad: int):
          + jax.lax.broadcasted_iota(jnp.int32, (TILE_ROWS, TILE_COLS), 0)
          * TILE_COLS
          + jax.lax.broadcasted_iota(jnp.int32, (TILE_ROWS, TILE_COLS), 1))
-    rank = jnp.zeros((TILE_ROWS, TILE_COLS), jnp.int32)
-    for c in range(f_pad // PREFIX_CHUNK):
-        chunk = prefix_ref[c * PREFIX_CHUNK:(c + 1) * PREFIX_CHUNK]
+
+    def body(c, rank):
+        chunk = prefix_ref[c]       # row c of the [F/128, 128] layout
         # rank += #prefix entries ≤ k   (broadcast compare over the chunk)
         le = (chunk[None, None, :] <= k[:, :, None])
-        rank = rank + jnp.sum(le.astype(jnp.int32), axis=-1)
-    out_ref[...] = rank
+        return rank + jnp.sum(le.astype(jnp.int32), axis=-1)
+    # an in-kernel loop: one chunk's temporaries, code size flat in F
+    out_ref[...] = jax.lax.fori_loop(
+        0, f_pad // PREFIX_CHUNK, body,
+        jnp.zeros((TILE_ROWS, TILE_COLS), jnp.int32))
 
 
 @partial(jax.jit, static_argnames=("cap_work", "interpret"))
@@ -53,12 +56,15 @@ def find_offsets(prefix: jax.Array, cap_work: int,
         return jnp.zeros((cap_work,), jnp.int32)  # like searchsorted
     f_pad = -(-f // PREFIX_CHUNK) * PREFIX_CHUNK
     big = jnp.iinfo(jnp.int32).max
-    prefix_p = jnp.pad(prefix, (0, f_pad - f), constant_values=big)
+    prefix_p = jnp.pad(prefix, (0, f_pad - f),
+                       constant_values=big).reshape(-1, PREFIX_CHUNK)
     n_tiles = -(-cap_work // TILE)
     out = pl.pallas_call(
         partial(_kernel, f_pad=f_pad),
         grid=(n_tiles,),
-        in_specs=[pl.BlockSpec((f_pad,), lambda i: (0,))],  # prefix in VMEM
+        # the whole prefix resident in VMEM, one 128-entry row per chunk
+        in_specs=[pl.BlockSpec((f_pad // PREFIX_CHUNK, PREFIX_CHUNK),
+                               lambda i: (0, 0))],
         out_specs=pl.BlockSpec((TILE_ROWS, TILE_COLS), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_tiles * TILE_ROWS, TILE_COLS),
                                        jnp.int32),

@@ -186,3 +186,27 @@ def test_mteps_zero_time_guard():
         setup_seconds=0.0, kernel_seconds=0.0, overhead_seconds=0.0,
         edges_relaxed=0, iter_stats=[], strategy="WD", state_bytes=0)
     assert res.mteps == 0.0 and res.mteps_with_setup == 0.0
+
+
+@pytest.mark.parametrize("strategy", ["BS", "WD", "HP", "NS", "AD"])
+def test_fused_multi_block_batches_match_stepped(monkeypatch, strategy):
+    """Batches wider than the largest lane block run as a loop of blocks
+    reading a snapshot of the values at the batch's start; shrinking the
+    block sizes forces that path (and every switch branch) on a small
+    graph, which must stay bit-identical to the stepped driver."""
+    import jax
+
+    g = GRAPHS["g500"]
+    hub = int(np.argmax(np.diff(np.asarray(g.row_ptr))))
+    stepped = engine.run(g, hub, engine.make_strategy(strategy))
+    assert stepped.edges_relaxed > 64       # some batch needs the loop
+    monkeypatch.setattr(fused, "_BLOCK_SIZES", (4, 16, 64))
+    jax.clear_caches()
+    try:
+        fusedr = engine.run(g, hub, engine.make_strategy(strategy),
+                            mode="fused")
+    finally:
+        jax.clear_caches()
+    np.testing.assert_array_equal(fusedr.dist, stepped.dist)
+    assert fusedr.iterations == stepped.iterations
+    assert fusedr.edges_relaxed == stepped.edges_relaxed

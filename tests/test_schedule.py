@@ -18,6 +18,7 @@ Four contracts:
 """
 
 import math
+import os
 import zlib
 
 import numpy as np
@@ -286,6 +287,30 @@ def test_costmodel_calibrate_and_cache(tmp_path):
         g, sched=Schedule(min_bucket=1024), cache_dir=str(tmp_path),
         repeats=1)
     assert not hit3
+
+
+def test_costmodel_cache_is_keyed_on_the_device(tmp_path, monkeypatch):
+    """A calibration timed on one device is never loaded on another:
+    platform and device kind are part of the signature and the file
+    name."""
+    import jax
+    g = _small_graph()
+    sig = costmodel.graph_signature(g, "xla")
+    dev = jax.devices()[0]
+    assert (sig["platform"], sig["device_kind"]) == (dev.platform,
+                                                     dev.device_kind)
+    tpu = dict(sig, platform="tpu", device_kind="TPU v5 lite")
+    path = costmodel.cache_path(str(tmp_path), tpu)
+    assert path != costmodel.cache_path(str(tmp_path), sig)
+    assert os.path.basename(path).startswith("costmodel-tpu-TPU-v5-lite-")
+    # a calibration made here, planted under the chip's file name, is
+    # re-measured rather than loaded there: its signature names this
+    # device
+    model, _ = costmodel.calibrate(g, cache_dir=str(tmp_path), repeats=1)
+    model.save(path)
+    monkeypatch.setattr(costmodel, "graph_signature", lambda *a, **k: tpu)
+    _, hit = costmodel.calibrate(g, cache_dir=str(tmp_path), repeats=1)
+    assert not hit
 
 
 def test_costmodel_rejects_foreign_cache_payload():

@@ -112,6 +112,22 @@ for kw in (dict(switch_threshold=4, mdt=3), dict(switch_threshold=16, mdt=7)):
                          mode="fused", shards=8)
     check(f"HP-big/{kw['switch_threshold']}", sharded, single, stepped)
 
+# --- BS/NS columns wider than the smallest lane block: a BFS level with
+# more walking nodes than 4 shards x 256 lanes, so some shard's column
+# runs in a wider block of the switch
+wide = rmat_graph(scale=12, edge_factor=8, weighted=False, seed=3)
+deg = np.diff(np.asarray(wide.row_ptr))
+hub = int(np.argmax(deg))
+levels = np.asarray(engine.run(wide, hub, engine.make_strategy("WD"),
+                               mode="fused").dist)
+assert max(np.sum((levels == lv) & (deg > 0))
+           for lv in range(1, 4)) > 4 * 256
+for strat in ("BS", "NS"):
+    single = engine.run(wide, hub, engine.make_strategy(strat), mode="fused")
+    sharded = engine.run(wide, hub, engine.make_strategy(strat),
+                         mode="fused", shards=4)
+    check(f"{strat}/wide", sharded, single)
+
 # --- pallas backend: per-shard Pallas kernels with the ghost combine
 # fused into the kernel epilogue
 # (docs/backends.md#sharded-pallas-the-fused-ghost-combine)
@@ -211,6 +227,7 @@ def test_sharded_bit_parity_matrix(parity):
     """Acceptance: 8-virtual-device sharded runs are bit-identical to the
     single-device paths for every SHARDABLE strategy × built-in op."""
     # 4 strategies × 3 monotone ops + 4 reach_count + 2 HP-big-branch +
+    # 2 wide BS/NS columns +
     # 4 pallas strategies + 2 pallas monoids + pallas counters +
     # 2 partition methods + batch + CC + dispatch counting
     assert parity["cases"] >= 30
