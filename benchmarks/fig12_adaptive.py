@@ -49,11 +49,6 @@ def _batch_sources(g, k: int) -> np.ndarray:
     return np.asarray(order[:k], np.int32)
 
 
-def _kernel_counts(res) -> dict:
-    kernels = [st.kernel for st in res.iter_stats]
-    return {k: kernels.count(k) for k in sorted(set(kernels))}
-
-
 def _model_vs_tree(model, g, v2_res) -> dict:
     """Price the tree's hypothetical picks along the v2 run's trace.
 
@@ -118,7 +113,8 @@ def run(verbose: bool = True):
                        "mteps": safe_mteps(res)}
                 if s == "AD":
                     ad_tree = res
-                    row["kernel_schedule"] = _kernel_counts(res)
+                    row["kernel_schedule"] = dict(
+                        sorted(res.kernel_counts.items()))
                 rows.append(row)
             except MemoryError as exc:
                 rows.append({"graph": gname, "strategy": s,
@@ -137,7 +133,8 @@ def run(verbose: bool = True):
                "iterations": res2.iterations,
                "edges_relaxed": res2.edges_relaxed,
                "mteps": safe_mteps(res2),
-               "kernel_schedule": _kernel_counts(res2),
+               "kernel_schedule": dict(
+                   sorted(res2.kernel_counts.items())),
                "calibration_cache_hit": bool(cache_hit)}
         row["model_vs_tree"] = _model_vs_tree(model, g, res2)
         if ad_tree is not None:

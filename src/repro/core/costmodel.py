@@ -270,11 +270,11 @@ def measure(graph: CSRGraph, *, backend: str = "xla",
 
     steps = {
         "BS": jax.jit(lambda d, m: fused._bs_step(
-            graph, d, m, backend=backend, sched=resolved)),
+            graph, d, m, backend=backend, sched=resolved)[:3]),
         "WD": jax.jit(lambda d, m: fused._wd_step(
-            graph, d, m, backend=backend, sched=resolved)),
+            graph, d, m, backend=backend, sched=resolved)[:3]),
         "HP": jax.jit(lambda d, m: fused._hp_step(
-            graph, d, m, backend=backend, sched=resolved)),
+            graph, d, m, backend=backend, sched=resolved)[:3]),
     }
     assert tuple(steps) == KERNELS
 

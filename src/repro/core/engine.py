@@ -39,9 +39,9 @@ from repro.core import shard as _shard
 from repro.core.graph import CSRGraph, INF
 from repro.core.schedule import Schedule
 from repro.core.strategies import (
-    BACKENDS, EdgeBased, FRONTIER_INIT, IterStats, NodeSplitting,
-    PALLAS_BACKEND, PRIORITY_SCHEDULE, SHARDABLE, StrategyBase,
-    make_strategy, pallas_relax)  # noqa: F401  (make_strategy re-exported: engine.make_strategy)
+    BACKENDS, AdaptiveStrategy, EdgeBased, FRONTIER_INIT, IterStats,
+    NodeSplitting, PALLAS_BACKEND, PRIORITY_SCHEDULE, SHARDABLE,
+    StrategyBase, make_strategy, pallas_relax)  # noqa: F401  (make_strategy re-exported: engine.make_strategy)
 
 #: work-ordering schedules engine.run/fixed_point/run_batch accept:
 #: "bsp" relaxes the whole frontier every iteration (bulk-synchronous,
@@ -55,7 +55,9 @@ class RunResult:
     dist: np.ndarray                 # [N] final distances / levels
     iterations: int
     total_seconds: float
-    setup_seconds: float             # strategy overhead (prep, conversion)
+    #: strategy set-up, plans and the seeded start arrays (prep,
+    #: conversion) — the interval of the ``engine.setup`` host span
+    setup_seconds: float
     kernel_seconds: float            # useful relax time (paper's split)
     overhead_seconds: float          # scan/compaction/push bookkeeping
     edges_relaxed: int
@@ -95,6 +97,17 @@ class RunResult:
     #: string above; see docs/schedules.md for the naming split.  None on
     #: degenerate no-edge runs.
     work_schedule: Optional[Schedule] = None
+    #: AD's kernel choices, ``{kernel: iterations}`` over the iterations
+    #: AD ran (fused and stepped); empty for the other strategies and
+    #: for the shards=/delta paths
+    kernel_counts: dict = dataclasses.field(default_factory=dict)
+    #: relax blocks the single-device fused loop ran (the lane blocks of
+    #: its relax batches, ``fused._relax_batch``) and the sum of their
+    #: widths; ``edges_relaxed / lanes_run`` is the share of lanes that
+    #: did work.  None on the paths that do not count them (stepped,
+    #: shards=, schedule="delta")
+    relax_batches: Optional[int] = None
+    lanes_run: Optional[int] = None
 
     def __post_init__(self):
         if self.relax_rounds is None:
@@ -331,36 +344,40 @@ def run(graph: CSRGraph, source: int, strategy: StrategyBase, *,
                          state_bytes=0, mode=mode, shards=shards or 1,
                          backend=backend, schedule=schedule, delta=delta,
                          async_shards=async_shards)
+    # host spans (engine.setup here, engine.dispatch / engine.wait /
+    # engine.readback around the fused call): no-ops unless a profiler runs
     t0 = time.perf_counter()
-    state = strategy.setup(graph)
-    splan = None
-    dplan = None
-    if shards is not None:
-        # partitioning is one-off host preprocessing, booked as setup
-        # like the NS morph / EP COO conversion
-        splan = _shard.plan_shards(strategy, state, graph, shards,
-                                   method=partition)
-    if schedule == "delta":
-        # the light/heavy edge split is host preprocessing too
-        dplan = _priority.plan_delta(strategy, state, graph, op=op,
-                                     delta=delta)
-        delta = dplan.delta          # surface the auto-tuned width
-    _ready(jax.tree_util.tree_leaves(state))
+    with jax.profiler.TraceAnnotation("engine.setup"):
+        state = strategy.setup(graph)
+        splan = None
+        dplan = None
+        if shards is not None:
+            # partitioning is one-off host preprocessing, booked as setup
+            # like the NS morph / EP COO conversion
+            splan = _shard.plan_shards(strategy, state, graph, shards,
+                                       method=partition)
+        if schedule == "delta":
+            # the light/heavy edge split is host preprocessing too
+            dplan = _priority.plan_delta(strategy, state, graph, op=op,
+                                         delta=delta)
+            delta = dplan.delta          # surface the auto-tuned width
+        _ready(jax.tree_util.tree_leaves(state))
+
+        if isinstance(strategy, NodeSplitting):
+            n_alloc = strategy.split_info.graph.num_nodes
+        else:
+            n_alloc = graph.num_nodes
+        if backend == "pallas":
+            _check_pallas_fit(strategy, n_alloc, graph.num_edges, splan)
+
+        dist = (jnp.full((n_alloc,), op.identity, op.dtype)
+                .at[source].set(op.seed(source)))
+        mask = jnp.zeros((n_alloc,), jnp.bool_).at[source].set(True)
     setup_s = time.perf_counter() - t0
 
-    if isinstance(strategy, NodeSplitting):
-        n_alloc = strategy.split_info.graph.num_nodes
-    else:
-        n_alloc = graph.num_nodes
-    if backend == "pallas":
-        _check_pallas_fit(strategy, n_alloc, graph.num_edges, splan)
-
-    dist = (jnp.full((n_alloc,), op.identity, op.dtype)
-            .at[source].set(op.seed(source)))
-
     if mode == "fused":
-        mask = jnp.zeros((n_alloc,), jnp.bool_).at[source].set(True)
         rounds = None
+        counters = {}
         t_start = time.perf_counter()
         if splan is not None:
             dist, iterations, edges, rounds = _shard.run_fixed_point(
@@ -373,10 +390,13 @@ def run(graph: CSRGraph, source: int, strategy: StrategyBase, *,
         else:
             dist, iterations, edges = _fused.run_fixed_point(
                 graph, state, strategy, dist, mask, op=op,
-                max_iterations=max_iterations, backend=backend)
+                max_iterations=max_iterations, backend=backend,
+                counters=counters)
         total_s = time.perf_counter() - t_start
-        if isinstance(strategy, NodeSplitting):
-            dist = strategy.split_info.extract_original(dist)
+        with jax.profiler.TraceAnnotation("engine.readback"):
+            if isinstance(strategy, NodeSplitting):
+                dist = strategy.split_info.extract_original(dist)
+            dist = np.asarray(dist)
         state_bytes = strategy.state_bytes(state)
         if splan is not None:
             state_bytes += splan.sharded.device_bytes()
@@ -385,14 +405,15 @@ def run(graph: CSRGraph, source: int, strategy: StrategyBase, *,
         # one dispatch: the kernel/overhead split collapses — the whole
         # traversal is kernel time, setup is the only host-side overhead
         return RunResult(
-            dist=np.asarray(dist), iterations=iterations,
+            dist=dist, iterations=iterations,
             total_seconds=total_s + setup_s, setup_seconds=setup_s,
             kernel_seconds=total_s, overhead_seconds=setup_s,
             edges_relaxed=edges, iter_stats=[], strategy=strategy.name,
             state_bytes=state_bytes, mode="fused", shards=shards or 1,
             backend=backend, schedule=schedule, delta=delta,
             relax_rounds=rounds, async_shards=async_shards,
-            work_schedule=getattr(strategy, "resolved_schedule", None))
+            work_schedule=getattr(strategy, "resolved_schedule", None),
+            **counters)
 
     iter_stats: list[IterStats] = []
     kernel_s = 0.0
@@ -412,7 +433,6 @@ def run(graph: CSRGraph, source: int, strategy: StrategyBase, *,
         # syncs the frontier count between epochs (the delta analogue of
         # the per-iteration stepped loop) and records which bucket each
         # epoch settled — the invariant tests read it back
-        mask = jnp.zeros((n_alloc,), jnp.bool_).at[source].set(True)
         count, it, rounds = 1, 0, 0
         while count > 0 and it < max_iterations:
             tk = time.perf_counter()
@@ -443,7 +463,6 @@ def run(graph: CSRGraph, source: int, strategy: StrategyBase, *,
                                         edges_processed=int(relaxed)))
             it += 1
     else:
-        mask = jnp.zeros((n_alloc,), jnp.bool_).at[source].set(True)
         count, it = 1, 0
         while count > 0 and it < max_iterations:
             tk = time.perf_counter()
@@ -474,7 +493,9 @@ def run(graph: CSRGraph, source: int, strategy: StrategyBase, *,
         state_bytes=state_bytes, mode="stepped",
         backend=backend, schedule=schedule, delta=delta,
         relax_rounds=rounds,
-        work_schedule=getattr(strategy, "resolved_schedule", None))
+        work_schedule=getattr(strategy, "resolved_schedule", None),
+        kernel_counts=(dict(strategy.kernel_counts)
+                       if isinstance(strategy, AdaptiveStrategy) else {}))
 
 
 def fixed_point(graph: CSRGraph, strategy: StrategyBase, init, *,

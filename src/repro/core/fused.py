@@ -24,7 +24,9 @@ into one device-resident loop:
   prefix-sum over masked degrees + ``searchsorted`` (the same merge-path
   structure as the stepped WD kernel);
 * the carry accumulates ``(iterations, edges_relaxed)`` so the resulting
-  :class:`repro.core.engine.RunResult` stays comparable with stepped runs.
+  :class:`repro.core.engine.RunResult` stays comparable with stepped runs,
+  and tallies the relax blocks run and the lanes they ran
+  (``relax_batches``, ``lanes_run``).
 
 Every registered strategy has a fused lowering (see :func:`_plan`):
 
@@ -50,8 +52,24 @@ kernel    dense-mask semantics (chunk boundaries match the stepped driver,
           structure on device — frontier statistics (count, degree sum,
           max degree, imbalance) feed a branch index into ``lax.switch``
           over the BS/WD/HP bodies; kernel choices are tallied in the
-          carry and surfaced as ``AdaptiveStrategy.kernel_counts``
+          carry and surfaced as ``RunResult.kernel_counts``
 ========  =================================================================
+
+Device scopes: the lowering names its work with ``jax.named_scope``, so
+the op names a profiler records (the ``tf_op`` of each device op) carry
+stable names where the HLO numbers change with every compile.  The
+kernel's name (``BS``/``WD``/``HP``/``EP``/``NS``/``AD``, and within AD
+the branch's ``BS``/``WD``/``HP``) encloses three inner scopes:
+
+* ``frontier`` — the ``[N]``-wide passes over the frontier: masked
+  degrees, AD's statistics and choice, BS's degree order, HP's live
+  count and tiles, the loop condition;
+* ``lanemap`` — mapping lanes to edges: the merge path's prefix sum and
+  slot compaction, each block's ``lanes(lo, size)``;
+* ``relax`` — one block's candidates, improve test and scatter fold
+  (under ``backend="pallas"``, the fused search+relax kernel).
+
+Scopes are trace-time metadata: they change no result.
 
 Every step (and the fixed-point dispatcher) additionally takes
 ``backend="xla" | "pallas"``: "pallas" routes the per-chunk relax
@@ -149,6 +167,68 @@ def _block_sizes(cap: int, backend: str) -> tuple:
     return sizes + (top,) if top <= _BLOCK_SIZES[-1] else sizes
 
 
+def _no_tally():
+    """An empty relax tally ``(blocks, lanes_hi, lanes_lo)``: blocks run
+    and the lanes they ran, the lanes in two limbs like the edge total
+    (one BS iteration over a skewed frontier can run past 2^31 lanes)."""
+    return jnp.int32(0), jnp.int32(0), jnp.int32(0)
+
+
+def _tally_add(a, b):
+    """The sum of two tallies."""
+    hi, lo = _limb_add(a[1] + b[1], a[2], b[2])
+    return a[0] + b[0], hi, lo
+
+
+def _batch_tally(total, *, cap: int, backend: str):
+    """The tally of the blocks :func:`_relax_batch` runs for a batch of
+    ``total`` lanes: one block of the smallest size that holds it (the
+    smallest size when the batch is empty) or, past the largest,
+    ``ceil(total / largest)`` blocks of the largest."""
+    sizes = _block_sizes(cap, backend)
+    top = sizes[-1]
+    idx = jnp.sum((total > jnp.asarray(sizes, jnp.int32)).astype(jnp.int32))
+    one = jnp.asarray(sizes, jnp.int32)[jnp.minimum(idx, len(sizes) - 1)]
+    looped = total > top
+    blocks = jnp.where(looped, (total + top - 1) // top, 1)
+    return blocks, jnp.int32(0), jnp.where(looped, blocks * top, one)
+
+
+def _limb_pow2(count, size: int):
+    """``count * size`` as ``(hi, lo)`` limbs, ``size`` a static power of
+    two (``count`` int32 >= 0)."""
+    k = size.bit_length() - 1
+    if k >= 20:
+        return count << (k - 20), jnp.int32(0)
+    return count >> (20 - k), (count & ((1 << (20 - k)) - 1)) << k
+
+
+def _bs_tally(walking, *, n: int, backend: str):
+    """The tally of :func:`_bs_step`'s column batches, in closed form.
+
+    Column ``d`` relaxes ``live_d = #{deg > d}`` lanes, and the columns
+    with more than ``s`` lanes number ``-walking[s]``, the ``s+1``-th
+    largest degree.  So the columns that run each block size, and the
+    block-loop trips of columns past the largest (``ceil(live / top)``
+    = ``#{j >= 0: live > j * top}``), are differences and sums of a few
+    entries of ``walking``: counting them costs the loop nothing per
+    column, where a count in its carry cost a v5e ~12 us a column."""
+    sizes = _block_sizes(n, backend)
+    top = sizes[-1]
+
+    def over(s):
+        return -walking[s] if s < n else jnp.int32(0)
+
+    tally, prev = _no_tally(), over(0)
+    for size in sizes:
+        cols = prev - over(size)
+        tally = _tally_add(tally, (cols, *_limb_pow2(cols, size)))
+        prev = over(size)
+    trips = prev + sum((over(j * top) for j in range(1, -(-n // top))),
+                       jnp.int32(0))
+    return _tally_add(tally, (trips, *_limb_pow2(trips, top)))
+
+
 def _snapshot_relax(snap, dist, updated, src, dst, w, valid, *,
                     op: EdgeOp):
     """One block of a multi-block batch: candidates and the improve test
@@ -182,9 +262,12 @@ def _relax_batch(dist, updated, total, lanes, *, cap: int, op: EdgeOp,
 
     def one_block(size):
         def run(c):
-            src, dst, w = lanes(0, size)
-            valid = jnp.arange(size, dtype=jnp.int32) < total
-            dist, updated, _ = relax(c[0], c[1], src, dst, w, valid, op=op)
+            with jax.named_scope("lanemap"):
+                src, dst, w = lanes(0, size)
+            with jax.named_scope("relax"):
+                valid = jnp.arange(size, dtype=jnp.int32) < total
+                dist, updated, _ = relax(c[0], c[1], src, dst, w, valid,
+                                         op=op)
             return dist, updated
         return run
 
@@ -197,10 +280,12 @@ def _relax_batch(dist, updated, total, lanes, *, cap: int, op: EdgeOp,
 
             def body(b, c):
                 lo = b * size
-                src, dst, w = lanes(lo, size)
-                valid = lo + jnp.arange(size, dtype=jnp.int32) < total
-                return _snapshot_relax(snap, c[0], c[1], src, dst, w, valid,
-                                       op=op)
+                with jax.named_scope("lanemap"):
+                    src, dst, w = lanes(lo, size)
+                with jax.named_scope("relax"):
+                    valid = lo + jnp.arange(size, dtype=jnp.int32) < total
+                    return _snapshot_relax(snap, c[0], c[1], src, dst, w,
+                                           valid, op=op)
             return lax.fori_loop(0, (total + size - 1) // size, body, c)
         branches.append(loop)
     if len(branches) == 1:
@@ -220,29 +305,36 @@ def _merge_path_relax(g: CSRGraph, dist, updated, work, cursor=None, *,
     replacement for host compaction — and the lanes relax as one batch
     (:func:`_relax_batch`).  ``cursor`` (optional) offsets every node's
     read position into its adjacency list (the HP tail).  Returns
-    ``(dist, updated, total_work)``.
+    ``(dist, updated, total_work, tally)`` (:func:`_no_tally`).
 
     ``backend="pallas"`` fuses the search and the relax in one kernel
     (``repro.kernels.relax.wd_relax_lanes``) — the per-lane node index
-    never materializes."""
-    prefix = prefix_sum(work)
-    exclusive = prefix - work
-    total = prefix[-1]
-    start = g.row_ptr[:-1] if cursor is None else g.row_ptr[:-1] + cursor
+    never materializes; it counts as one block of ``E`` lanes."""
+    with jax.named_scope("lanemap"):
+        prefix = prefix_sum(work)
+        exclusive = prefix - work
+        total = prefix[-1]
+        start = (g.row_ptr[:-1] if cursor is None
+                 else g.row_ptr[:-1] + cursor)
     if backend == "pallas":
-        src_ids = jnp.arange(g.num_nodes, dtype=jnp.int32)
-        prop, upd, _ = pallas_relax.wd_relax_lanes(
-            dist, prefix, exclusive, start, src_ids, g.col, g.wt,
-            cap_work=g.num_edges, op=op, **pallas_relax.tile_kwargs(sched))
-        return (pallas_relax.apply_proposal(dist, prop, op),
-                updated | upd, total)
-    lanes = _merge_path_lanes(work, prefix, start - exclusive, g.col, g.wt,
-                              num_edges=g.num_edges,
-                              pad=_block_sizes(g.num_edges, backend)[-1])
+        with jax.named_scope("relax"):
+            src_ids = jnp.arange(g.num_nodes, dtype=jnp.int32)
+            prop, upd, _ = pallas_relax.wd_relax_lanes(
+                dist, prefix, exclusive, start, src_ids, g.col, g.wt,
+                cap_work=g.num_edges, op=op,
+                **pallas_relax.tile_kwargs(sched))
+            dist = pallas_relax.apply_proposal(dist, prop, op)
+        return dist, updated | upd, total, (jnp.int32(1), jnp.int32(0),
+                                            jnp.int32(g.num_edges))
+    with jax.named_scope("lanemap"):
+        lanes = _merge_path_lanes(work, prefix, start - exclusive, g.col,
+                                  g.wt, num_edges=g.num_edges,
+                                  pad=_block_sizes(g.num_edges, backend)[-1])
     dist, updated = _relax_batch(dist, updated, total, lanes,
                                  cap=g.num_edges, op=op, backend=backend,
                                  sched=sched)
-    return dist, updated, total
+    return dist, updated, total, _batch_tally(total, cap=g.num_edges,
+                                              backend=backend)
 
 
 def _bs_step(g: CSRGraph, dist, mask, *,
@@ -256,16 +348,21 @@ def _bs_step(g: CSRGraph, dist, mask, *,
     compacted frontier, so intra-iteration propagation is identical.
     The frontier is ordered by degree once per iteration, so column
     ``d``'s lanes are a prefix of that order and a column costs the
-    nodes still walking, not ``N`` (:func:`_relax_batch`)."""
-    deg = _masked_degrees(g, mask)
+    nodes still walking, not ``N`` (:func:`_relax_batch`).
+
+    Every step returns ``(dist, updated, edges, tally)``, ``tally`` the
+    relax blocks the step ran (:func:`_no_tally`)."""
     n = g.num_nodes
-    pad = _block_sizes(n, backend)[-1]
-    order = jnp.argsort(deg, descending=True, stable=True).astype(jnp.int32)
-    walking = -deg[order]                   # ascending: -degree by rank
-    order = jnp.pad(order, (0, pad))
+    with jax.named_scope("frontier"):
+        deg = _masked_degrees(g, mask)
+        pad = _block_sizes(n, backend)[-1]
+        order = jnp.argsort(deg, descending=True,
+                            stable=True).astype(jnp.int32)
+        walking = -deg[order]               # ascending: -degree by rank
+        order = jnp.pad(order, (0, pad))
+        fmax = jnp.max(deg)
+        updated = jnp.zeros_like(mask)
     base = g.row_ptr[:-1]
-    fmax = jnp.max(deg)
-    updated = jnp.zeros_like(mask)
 
     def cond(c):
         return c[0] < fmax
@@ -278,14 +375,17 @@ def _bs_step(g: CSRGraph, dist, mask, *,
             eidx = jnp.clip(base[src] + d, 0, g.num_edges - 1)
             return src, g.col[eidx], _edge_weight(g, eidx)
         # frontier nodes with degree > d walk column d
-        live = jnp.searchsorted(walking, -d, side="left").astype(jnp.int32)
+        with jax.named_scope("frontier"):
+            live = jnp.searchsorted(walking, -d,
+                                    side="left").astype(jnp.int32)
         dist, updated = _relax_batch(dist, updated, live, lanes, cap=n,
                                      op=op, backend=backend, sched=sched)
         return d + 1, dist, updated
 
     _, dist, updated = lax.while_loop(cond, body,
                                       (jnp.int32(0), dist, updated))
-    return dist, updated, jnp.sum(deg)
+    return dist, updated, jnp.sum(deg), _bs_tally(walking, n=n,
+                                                  backend=backend)
 
 
 def _wd_step(g: CSRGraph, dist, mask, *,
@@ -295,11 +395,11 @@ def _wd_step(g: CSRGraph, dist, mask, *,
 
     One synchronous ``_merge_path_relax`` over the masked degrees — same
     snapshot semantics as ``wd_relax``."""
-    deg = _masked_degrees(g, mask)
-    updated = jnp.zeros_like(mask)
-    dist, updated, total = _merge_path_relax(g, dist, updated, deg, op=op,
-                                             backend=backend, sched=sched)
-    return dist, updated, total
+    with jax.named_scope("frontier"):
+        deg = _masked_degrees(g, mask)
+        updated = jnp.zeros_like(mask)
+    return _merge_path_relax(g, dist, updated, deg, op=op, backend=backend,
+                             sched=sched)
 
 
 def _hp_step(g: CSRGraph, dist, mask, *, sched: Schedule = DEFAULT_SCHEDULE,
@@ -313,18 +413,20 @@ def _hp_step(g: CSRGraph, dist, mask, *, sched: Schedule = DEFAULT_SCHEDULE,
     propagation — match ``HierarchicalProcessing.iterate`` exactly."""
     mdt = sched.mdt or 1
     switch_threshold = sched.switch_threshold
-    deg = _masked_degrees(g, mask)
-    count = jnp.sum(mask.astype(jnp.int32))
+    with jax.named_scope("frontier"):
+        deg = _masked_degrees(g, mask)
+        count = jnp.sum(mask.astype(jnp.int32))
     n = g.num_nodes
 
     def small(dist):
-        dist, updated, _ = _wd_step(g, dist, mask, op=op, backend=backend,
-                                    sched=sched)
-        return dist, updated
+        dist, updated, _, tally = _wd_step(g, dist, mask, op=op,
+                                           backend=backend, sched=sched)
+        return dist, updated, tally
 
     def big(dist):
         def live(cursor):
-            return jnp.sum((mask & (cursor < deg)).astype(jnp.int32))
+            with jax.named_scope("frontier"):
+                return jnp.sum((mask & (cursor < deg)).astype(jnp.int32))
 
         def cond(c):
             i, cursor = c[0], c[1]
@@ -335,30 +437,35 @@ def _hp_step(g: CSRGraph, dist, mask, *, sched: Schedule = DEFAULT_SCHEDULE,
         def body(c):
             # one sub-iteration: the next <= MDT edges of every live node,
             # one relax batch (the [N, MDT] tile's valid lanes)
-            i, cursor, dist, updated = c
-            tile = jnp.clip(deg - cursor, 0, mdt)
-            dist, updated, _ = _merge_path_relax(g, dist, updated, tile,
-                                                 cursor, op=op,
-                                                 backend=backend,
-                                                 sched=sched)
-            return i + 1, cursor + mdt, dist, updated
+            i, cursor, dist, updated, tally = c
+            with jax.named_scope("frontier"):
+                tile = jnp.clip(deg - cursor, 0, mdt)
+            dist, updated, _, t = _merge_path_relax(
+                g, dist, updated, tile, cursor, op=op, backend=backend,
+                sched=sched)
+            with jax.named_scope("frontier"):
+                cursor = cursor + mdt
+            return i + 1, cursor, dist, updated, _tally_add(tally, t)
 
         i0 = jnp.int32(0)
-        cursor0 = jnp.zeros((n,), jnp.int32)
-        upd0 = jnp.zeros_like(mask)
-        _, cursor, dist, updated = lax.while_loop(
-            cond, body, (i0, cursor0, dist, upd0))
+        with jax.named_scope("frontier"):
+            cursor0 = jnp.zeros((n,), jnp.int32)
+            upd0 = jnp.zeros_like(mask)
+        _, cursor, dist, updated, tally = lax.while_loop(
+            cond, body, (i0, cursor0, dist, upd0, _no_tally()))
 
         # cursor-aware WD tail over the surviving sublist (≤ threshold
         # nodes, all remaining edges in one synchronous pass)
-        rem = jnp.where(mask, jnp.maximum(deg - cursor, 0), 0)
-        dist, updated, _ = _merge_path_relax(g, dist, updated, rem, cursor,
-                                             op=op, backend=backend,
-                                             sched=sched)
-        return dist, updated
+        with jax.named_scope("frontier"):
+            rem = jnp.where(mask, jnp.maximum(deg - cursor, 0), 0)
+        dist, updated, _, t = _merge_path_relax(
+            g, dist, updated, rem, cursor, op=op, backend=backend,
+            sched=sched)
+        return dist, updated, _tally_add(tally, t)
 
-    dist, updated = lax.cond(count <= switch_threshold, small, big, dist)
-    return dist, updated, jnp.sum(deg)
+    dist, updated, tally = lax.cond(count <= switch_threshold, small, big,
+                                    dist)
+    return dist, updated, jnp.sum(deg), tally
 
 
 def _ep_step(g: CSRGraph, edge_src, dist, mask, *,
@@ -367,13 +474,19 @@ def _ep_step(g: CSRGraph, edge_src, dist, mask, *,
     """Dense EP: all ``E`` edge lanes, valid where the source is live.
 
     The dense analogue of a chunked edge worklist — deduplicated by
-    construction, one synchronous relax per iteration."""
-    valid = mask[edge_src]
-    eidx = jnp.arange(g.num_edges, dtype=jnp.int32)
-    updated = jnp.zeros_like(mask)
-    dist, updated, _ = relax_fn(backend, sched)(
-        dist, updated, edge_src, g.col, _edge_weight(g, eidx), valid, op=op)
-    return dist, updated, jnp.sum(valid.astype(jnp.int32))
+    construction, one synchronous relax per iteration: one block of
+    ``E`` lanes."""
+    with jax.named_scope("frontier"):
+        valid = mask[edge_src]
+        updated = jnp.zeros_like(mask)
+        edges = jnp.sum(valid.astype(jnp.int32))
+    with jax.named_scope("lanemap"):
+        w = _edge_weight(g, jnp.arange(g.num_edges, dtype=jnp.int32))
+    with jax.named_scope("relax"):
+        dist, updated, _ = relax_fn(backend, sched)(
+            dist, updated, edge_src, g.col, w, valid, op=op)
+    return dist, updated, edges, (jnp.int32(1), jnp.int32(0),
+                                  jnp.int32(g.num_edges))
 
 
 def _ns_step(g2: CSRGraph, child_parent, dist, mask, *,
@@ -382,8 +495,9 @@ def _ns_step(g2: CSRGraph, child_parent, dist, mask, *,
     """Dense NS: mirror parent attributes onto children (the
     ``ns_activate`` gather — operator-generic, see strategies.py), then
     dense BS on the split graph."""
-    dist = dist[child_parent]
-    mask = mask | mask[child_parent]
+    with jax.named_scope("frontier"):
+        dist = dist[child_parent]
+        mask = mask | mask[child_parent]
     return _bs_step(g2, dist, mask, op=op, backend=backend, sched=sched)
 
 
@@ -394,8 +508,9 @@ def _ad_step(g: CSRGraph, dist, mask, *, sched: Schedule = DEFAULT_SCHEDULE,
 
     Frontier statistics (count, degree sum, max degree, imbalance =
     max/mean per-node work) produce a branch index for ``lax.switch``
-    over the dense BS/WD/HP bodies.  Returns the index so the caller can
-    tally the kernel schedule in the loop carry.
+    over the dense BS/WD/HP bodies, each in a scope of its kernel's name.
+    Returns ``(dist, updated, edges, index, tally)``: the index so the
+    caller can tally the kernel schedule in the loop carry.
 
     Two selectors, chosen at trace time:
 
@@ -412,6 +527,24 @@ def _ad_step(g: CSRGraph, dist, mask, *, sched: Schedule = DEFAULT_SCHEDULE,
       ``CostModel.choose`` — same lockstep rule.  Degenerate frontiers
       (no edges / empty mask) still take BS on both selectors."""
     mdt = sched.mdt or 1
+    with jax.named_scope("frontier"):
+        idx = _ad_choice(g, mask, sched=sched, mdt=mdt, coeffs=coeffs)
+
+    def branch(name, step):
+        def run(dist):
+            with jax.named_scope(name):
+                return step(g, dist, mask, op=op, backend=backend,
+                            sched=sched)
+        return run
+
+    dist, updated, edges, tally = lax.switch(
+        idx, [branch(name, step) for name, step in zip(
+            _AD_KERNEL_ORDER, (_bs_step, _wd_step, _hp_step))], dist)
+    return dist, updated, edges, idx, tally
+
+
+def _ad_choice(g: CSRGraph, mask, *, sched: Schedule, mdt: int, coeffs):
+    """The branch index of :func:`_ad_step`'s selector."""
     deg = _masked_degrees(g, mask)
     count = jnp.sum(mask.astype(jnp.int32))
     degree_sum = jnp.sum(deg)
@@ -429,25 +562,12 @@ def _ad_step(g: CSRGraph, dist, mask, *, sched: Schedule = DEFAULT_SCHEDULE,
                          <= jnp.float32(sched.imbalance_threshold))))
         take_hp = ((max_degree > mdt)
                    & (degree_sum >= sched.hp_edges_threshold))
-        idx = jnp.where(take_bs, 0,
-                        jnp.where(take_hp, 2, 1)).astype(jnp.int32)
-    else:
-        es = degree_sum.astype(jnp.float32)
-        cn = count.astype(jnp.float32)
-        costs = coeffs[:, 0] + coeffs[:, 1] * es + coeffs[:, 2] * cn
-        idx = jnp.where(degenerate, 0,
-                        jnp.argmin(costs).astype(jnp.int32))
-
-    dist, updated, edges = lax.switch(
-        idx,
-        [lambda d: _bs_step(g, d, mask, op=op, backend=backend,
-                            sched=sched),
-         lambda d: _wd_step(g, d, mask, op=op, backend=backend,
-                            sched=sched),
-         lambda d: _hp_step(g, d, mask, sched=sched, op=op,
-                            backend=backend)],
-        dist)
-    return dist, updated, edges, idx
+        return jnp.where(take_bs, 0,
+                         jnp.where(take_hp, 2, 1)).astype(jnp.int32)
+    es = degree_sum.astype(jnp.float32)
+    cn = count.astype(jnp.float32)
+    costs = coeffs[:, 0] + coeffs[:, 1] * es + coeffs[:, 2] * cn
+    return jnp.where(degenerate, 0, jnp.argmin(costs).astype(jnp.int32))
 
 
 # ---------------------------------------------------------------------------
@@ -484,56 +604,61 @@ def _fixed_point(g: CSRGraph, aux, dist, mask, *, kernel: str,
     (static) edge operator defining the relax semantics, and ``backend``
     picks the relax lowering (XLA gather/scatter vs the Pallas fused
     scatter-combine — same chunk schedule, bit-identical results).  The
-    carry is ``(it, dist, mask, edges_hi, edges_lo, kernel_counts)`` —
-    the edge total rides in a two-limb int32 accumulator (``_limb_add``)
-    so it stays exact past 2^31; ``kernel_counts`` only moves for
-    ``AD``."""
+    carry is ``(it, dist, mask, edges_hi, edges_lo, kernel_counts,
+    tally)`` — the edge total rides in a two-limb int32 accumulator
+    (``_limb_add``) so it stays exact past 2^31; ``kernel_counts`` only
+    moves for ``AD``; ``tally`` is the relax blocks run and their lanes
+    (:func:`_no_tally`)."""
     # Python side effect ⇒ counts compilations, keyed per backend so the
     # XLA cache entry observably survives backend switches
     TRACE_COUNTS[_count_key(kernel, backend)] += 1
 
     def frontier_live(mask):
-        if kernel == "EP":
-            # the edge-worklist driver stops when the frontier has no
-            # outgoing edges, one round before the node drivers
-            return jnp.sum(_masked_degrees(g, mask)) > 0
-        return jnp.any(mask)
+        with jax.named_scope("frontier"):
+            if kernel == "EP":
+                # the edge-worklist driver stops when the frontier has no
+                # outgoing edges, one round before the node drivers
+                return jnp.sum(_masked_degrees(g, mask)) > 0
+            return jnp.any(mask)
 
     def cond(c):
         it, _, mask = c[0], c[1], c[2]
         return frontier_live(mask) & (it < max_iterations)
 
-    def body(c):
-        it, dist, mask, e_hi, e_lo, kcounts = c
+    def step(dist, mask):
+        kw = dict(op=op, backend=backend, sched=sched)
         if kernel == "BS":
-            dist, new_mask, e = _bs_step(g, dist, mask, op=op,
-                                         backend=backend, sched=sched)
-        elif kernel == "WD":
-            dist, new_mask, e = _wd_step(g, dist, mask, op=op,
-                                         backend=backend, sched=sched)
-        elif kernel == "HP":
-            dist, new_mask, e = _hp_step(g, dist, mask, sched=sched,
-                                         op=op, backend=backend)
-        elif kernel == "EP":
-            dist, new_mask, e = _ep_step(g, aux, dist, mask, op=op,
-                                         backend=backend, sched=sched)
-        elif kernel == "NS":
-            dist, new_mask, e = _ns_step(g, aux, dist, mask, op=op,
-                                         backend=backend, sched=sched)
-        elif kernel == "AD":
-            dist, new_mask, e, idx = _ad_step(
-                g, dist, mask, sched=sched, op=op, backend=backend,
-                coeffs=aux if measured else None)
-            kcounts = kcounts.at[idx].add(1)
-        else:  # pragma: no cover - guarded by _plan
-            raise ValueError(f"unknown fused kernel {kernel!r}")
+            return _bs_step(g, dist, mask, **kw)
+        if kernel == "WD":
+            return _wd_step(g, dist, mask, **kw)
+        if kernel == "HP":
+            return _hp_step(g, dist, mask, **kw)
+        if kernel == "EP":
+            return _ep_step(g, aux, dist, mask, **kw)
+        if kernel == "NS":
+            return _ns_step(g, aux, dist, mask, **kw)
+        raise ValueError(  # pragma: no cover - guarded by _plan
+            f"unknown fused kernel {kernel!r}")
+
+    def body(c):
+        it, dist, mask, e_hi, e_lo, kcounts, tally = c
+        with jax.named_scope(kernel):
+            if kernel == "AD":
+                dist, new_mask, e, idx, t = _ad_step(
+                    g, dist, mask, sched=sched, op=op, backend=backend,
+                    coeffs=aux if measured else None)
+                kcounts = kcounts.at[idx].add(1)
+            else:
+                dist, new_mask, e, t = step(dist, mask)
         e_hi, e_lo = _limb_add(e_hi, e_lo, e)
-        return it + 1, dist, new_mask, e_hi, e_lo, kcounts
+        return (it + 1, dist, new_mask, e_hi, e_lo, kcounts,
+                _tally_add(tally, t))
 
     carry = (jnp.int32(0), dist, mask, jnp.int32(0), jnp.int32(0),
-             jnp.zeros((len(_AD_KERNEL_ORDER),), jnp.int32))
-    it, dist, mask, e_hi, e_lo, kcounts = lax.while_loop(cond, body, carry)
-    return dist, it, e_hi, e_lo, kcounts
+             jnp.zeros((len(_AD_KERNEL_ORDER),), jnp.int32), _no_tally())
+    it, dist, mask, e_hi, e_lo, kcounts, tally = lax.while_loop(
+        cond, body, carry)
+    return dist, it, e_hi, e_lo, kcounts, tally
 
 
 # ---------------------------------------------------------------------------
@@ -625,7 +750,8 @@ def _plan(strategy, state, graph: CSRGraph) -> FusedPlan:
 
 def run_fixed_point(graph: CSRGraph, state: Any, strategy, dist0, mask0, *,
                     op: EdgeOp = operators.shortest_path,
-                    max_iterations: int = 100000, backend: str = "xla"):
+                    max_iterations: int = 100000, backend: str = "xla",
+                    counters: Optional[dict] = None):
     """Run one strategy's whole traversal as a single fused dispatch.
 
     ``dist0``/``mask0`` are the initial value/frontier arrays on the
@@ -634,20 +760,33 @@ def run_fixed_point(graph: CSRGraph, state: Any, strategy, dist0, mask0, *,
     ``op`` is the edge operator defining what the traversal computes and
     ``backend`` the relax lowering (docs/backends.md).  Returns
     ``(dist, iterations, edges_relaxed)`` with the first still on
-    device; for AD the kernel tally is stored on the strategy as
-    ``kernel_counts``, mirroring the stepped driver."""
+    device.  ``counters``, when given, receives ``kernel_counts`` (AD's
+    choices, ``{}`` for the other kernels), ``relax_batches`` and
+    ``lanes_run`` (the relax blocks run and the lanes they ran).
+
+    The call, the wait and the scalar reads run in the host spans
+    ``engine.dispatch``, ``engine.wait`` and ``engine.readback``
+    (``jax.profiler.TraceAnnotation``: on the device trace's clock when a
+    profiler runs, no-ops otherwise)."""
     plan = _plan(strategy, state, graph)
     DISPATCH_COUNTS[_count_key(plan.kernel, backend)] += 1
     aux = (jnp.zeros((1,), jnp.int32) if plan.aux is None else plan.aux)
-    dist, it, e_hi, e_lo, kcounts = _fixed_point(
-        plan.graph, aux, dist0, mask0, kernel=plan.kernel,
-        max_iterations=max_iterations, op=operators.resolve(op),
-        backend=backend, **plan.static)
-    jax.block_until_ready(dist)
-    if plan.kernel == "AD":
-        counts = [int(c) for c in kcounts]
-        strategy.kernel_counts = {
-            name: c for name, c in zip(_AD_KERNEL_ORDER, counts) if c}
+    with jax.profiler.TraceAnnotation("engine.dispatch"):
+        dist, *scalars = _fixed_point(
+            plan.graph, aux, dist0, mask0, kernel=plan.kernel,
+            max_iterations=max_iterations, op=operators.resolve(op),
+            backend=backend, **plan.static)
+    with jax.profiler.TraceAnnotation("engine.wait"):
+        jax.block_until_ready(dist)
+    with jax.profiler.TraceAnnotation("engine.readback"):
+        it, e_hi, e_lo, kcounts, (blocks, l_hi, l_lo) = jax.device_get(
+            scalars)
+    if counters is not None:
+        counters["kernel_counts"] = (
+            {name: int(c) for name, c in zip(_AD_KERNEL_ORDER, kcounts) if c}
+            if plan.kernel == "AD" else {})
+        counters["relax_batches"] = int(blocks)
+        counters["lanes_run"] = int(l_hi) * _LIMB + int(l_lo)
     return dist, int(it), int(e_hi) * _LIMB + int(e_lo)
 
 
@@ -681,7 +820,7 @@ def _batch_fixed_point(g: CSRGraph, dist_b, mask_b, *,
         # picks its own lane-block size, and only that block runs
         dist_b, mask_b, e = lax.map(
             lambda dm: _wd_step(g, dm[0], dm[1], op=op, backend=backend,
-                                sched=sched),
+                                sched=sched)[:3],
             (dist_b, mask_b))
         # fold the K per-row totals one _limb_add at a time (each row is
         # < 2^31, but even the per-row remainders could wrap a plain

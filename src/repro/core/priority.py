@@ -215,19 +215,22 @@ def _phase(g: CSRGraph, aux, dist, cur, *, kernel: str, op: EdgeOp,
     if g.num_edges == 0:
         # static guard: HP's MDT tiles index g.col, which is empty here
         return dist, jnp.zeros_like(cur), jnp.int32(0)
+    # the fused steps' fourth result, their relax tally, is not kept here
     if kernel == "BS":
-        return _bs_step(g, dist, cur, op=op, backend=backend, sched=sched)
+        return _bs_step(g, dist, cur, op=op, backend=backend,
+                        sched=sched)[:3]
     if kernel == "WD":
-        return _wd_step(g, dist, cur, op=op, backend=backend, sched=sched)
+        return _wd_step(g, dist, cur, op=op, backend=backend,
+                        sched=sched)[:3]
     if kernel == "HP":
-        return _hp_step(g, dist, cur, sched=sched, op=op, backend=backend)
+        return _hp_step(g, dist, cur, sched=sched, op=op,
+                        backend=backend)[:3]
     if kernel == "NS":
         return _ns_step(g, aux, dist, cur, op=op, backend=backend,
-                        sched=sched)
+                        sched=sched)[:3]
     if kernel == "AD":
-        dist, updated, e, _idx = _ad_step(
-            g, dist, cur, sched=sched, op=op, backend=backend)
-        return dist, updated, e
+        return _ad_step(g, dist, cur, sched=sched, op=op,
+                        backend=backend)[:3]
     raise ValueError(f"kernel {kernel!r} has no delta-stepping phase")
 
 

@@ -87,7 +87,7 @@ def test_adaptive_switches_kernels():
     res = engine.run(g, 0, strat)
     used = {s.kernel for s in res.iter_stats}
     assert len(used) >= 2
-    assert sum(strat.kernel_counts.values()) == res.iterations
+    assert sum(res.kernel_counts.values()) == res.iterations
 
 
 def test_choose_kernel_decision_structure():

@@ -107,10 +107,10 @@ def test_fused_ad_reports_kernel_schedule():
     g = GRAPHS["rmat"]
     strat = engine.make_strategy("AD", small_frontier=8)
     res = engine.run(g, 0, strat, mode="fused")
-    assert sum(strat.kernel_counts.values()) == res.iterations
-    assert set(strat.kernel_counts) <= {"BS", "WD", "HP"}
+    assert sum(res.kernel_counts.values()) == res.iterations
+    assert set(res.kernel_counts) <= {"BS", "WD", "HP"}
     # a tight BS window on a skewed graph must exercise ≥ 2 kernels
-    assert len(strat.kernel_counts) >= 2
+    assert len(res.kernel_counts) >= 2
 
 
 def test_fused_mode_validation():
@@ -210,3 +210,176 @@ def test_fused_multi_block_batches_match_stepped(monkeypatch, strategy):
     np.testing.assert_array_equal(fusedr.dist, stepped.dist)
     assert fusedr.iterations == stepped.iterations
     assert fusedr.edges_relaxed == stepped.edges_relaxed
+
+
+# ---------------------------------------------------------------------------
+# the loop's own counters and names (RunResult.kernel_counts /
+# relax_batches / lanes_run, the jax.named_scope names, the host spans)
+# ---------------------------------------------------------------------------
+
+def _host_cost(total, cap):
+    """``(blocks, lanes)`` of one relax batch of ``total`` lanes: the
+    smallest block of ``_block_sizes`` that holds it, else a loop of the
+    largest."""
+    sizes = fused._block_sizes(cap, "xla")
+    fit = [s for s in sizes if total <= s]
+    if fit:
+        return 1, fit[0]
+    blocks = -(-total // sizes[-1])
+    return blocks, blocks * sizes[-1]
+
+
+def _host_recount(g, stepped, sched):
+    """``(relax_batches, lanes_run)`` recounted on the host from a stepped
+    run's per-iteration frontier degrees and the kernel each iteration
+    ran: BS one batch per edge column, WD one merge-path batch, HP one
+    batch per MDT tile while more than ``switch_threshold`` nodes have
+    edges left and one for the tail (straight WD at or below it)."""
+    n, e = g.num_nodes, g.num_edges
+    mdt, threshold = sched.mdt or 1, sched.switch_threshold
+    costs = []
+    for st in stepped.iter_stats:
+        deg = np.asarray(st.frontier_degrees, np.int64)
+        kernel = st.kernel or stepped.strategy
+        if kernel == "HP" and len(deg) <= threshold:
+            kernel = "WD"
+        if kernel == "BS":
+            costs += [_host_cost(int((deg > d).sum()), n)
+                      for d in range(int(deg.max(initial=0)))]
+        elif kernel == "WD":
+            costs.append(_host_cost(int(deg.sum()), e))
+        else:
+            cursor = 0
+            while True:
+                tile = np.clip(deg - cursor, 0, mdt).sum()
+                costs.append(_host_cost(int(tile), e))
+                cursor += mdt
+                if (deg > cursor).sum() <= threshold:
+                    break
+            costs.append(_host_cost(int(np.maximum(deg - cursor, 0).sum()),
+                                    e))
+    return sum(b for b, _ in costs), sum(lanes for _, lanes in costs)
+
+
+TALLIED = [("BS", {}), ("WD", {}), ("HP", {}),
+           ("HP", dict(switch_threshold=4, mdt=3)),
+           ("AD", dict(small_frontier=8))]
+
+
+@pytest.mark.parametrize("gname", ["rmat", "road"])
+@pytest.mark.parametrize("strategy,kw", TALLIED)
+def test_fused_relax_tally_matches_host_recount(gname, strategy, kw):
+    g = GRAPHS[gname]
+    stepped = engine.run(g, 0, engine.make_strategy(strategy, **kw),
+                         record_degrees=True)
+    fusedr = engine.run(g, 0, engine.make_strategy(strategy, **kw),
+                        mode="fused")
+    assert (fusedr.relax_batches, fusedr.lanes_run) == _host_recount(
+        g, stepped, fusedr.work_schedule)
+    assert fusedr.edges_relaxed <= fusedr.lanes_run
+    assert stepped.relax_batches is None and stepped.lanes_run is None
+
+
+@pytest.mark.parametrize("strategy", ["BS", "WD", "AD"])
+def test_fused_relax_tally_counts_block_loops(monkeypatch, strategy):
+    """Batches past the largest block count one block per trip of the
+    block loop (block sizes shrunk as in the multi-block test above)."""
+    import jax
+
+    g = GRAPHS["g500"]
+    hub = int(np.argmax(np.diff(np.asarray(g.row_ptr))))
+    stepped = engine.run(g, hub, engine.make_strategy(strategy),
+                         record_degrees=True)
+    monkeypatch.setattr(fused, "_BLOCK_SIZES", (4, 16, 64))
+    jax.clear_caches()
+    try:
+        fusedr = engine.run(g, hub, engine.make_strategy(strategy),
+                            mode="fused")
+    finally:
+        jax.clear_caches()
+    want = _host_recount(g, stepped, fusedr.work_schedule)
+    assert (fusedr.relax_batches, fusedr.lanes_run) == want
+    # WD runs one batch an iteration: more blocks than iterations is a
+    # batch that ran as a loop of 64-lane blocks
+    assert fusedr.relax_batches > fusedr.iterations
+
+
+@pytest.mark.parametrize("mode", ["stepped", "fused"])
+def test_run_result_kernel_counts(mode):
+    g = GRAPHS["rmat"]
+    res = engine.run(g, 0, engine.make_strategy("AD", small_frontier=8),
+                     mode=mode)
+    assert sum(res.kernel_counts.values()) == res.iterations
+    assert set(res.kernel_counts) <= {"BS", "WD", "HP"}
+    assert len(res.kernel_counts) >= 2
+    # the on-device selector picks what the host one picks
+    other = engine.run(g, 0, engine.make_strategy("AD", small_frontier=8),
+                       mode="fused" if mode == "stepped" else "stepped")
+    assert other.kernel_counts == res.kernel_counts
+    # a strategy that does not choose reports no choices
+    assert engine.run(g, 0, engine.make_strategy("WD"),
+                      mode=mode).kernel_counts == {}
+
+
+def test_uncounted_paths_leave_relax_tally_unset():
+    g = GRAPHS["road"]
+    delta = engine.run(g, 0, engine.make_strategy("WD"), mode="fused",
+                       schedule="delta")
+    stepped = engine.run(g, 0, engine.make_strategy("WD"))
+    for res in (delta, stepped):
+        assert res.relax_batches is None and res.lanes_run is None
+
+
+def test_fused_ad_lowering_names_its_scopes():
+    """The AD loop's HLO carries the program's scope names in its
+    ``op_name`` metadata — what a profiler's ``tf_op`` shows."""
+    import re
+
+    import jax.numpy as jnp
+
+    from repro.core import operators
+
+    g = GRAPHS["rmat"]
+    strat = engine.make_strategy("AD")
+    plan = fused._plan(strat, strat.setup(g), g)
+    dist = jnp.full((g.num_nodes,), operators.shortest_path.identity,
+                    jnp.int32).at[0].set(0)
+    mask = jnp.zeros((g.num_nodes,), jnp.bool_).at[0].set(True)
+    text = fused._fixed_point.lower(
+        g, jnp.zeros((1,), jnp.int32), dist, mask, kernel="AD",
+        max_iterations=100, **plan.static).as_text(dialect="hlo",
+                                                   debug_info=True)
+    paths = set(re.findall(r'op_name="([^"]*)"', text))
+    parts = {tuple(p.split("/")) for p in paths}
+    for branch in ("BS", "WD", "HP"):
+        for scope in ("frontier", "lanemap", "relax"):
+            assert any("AD" in q and branch in q and scope in q
+                       and q.index("AD") < q.index(branch) < q.index(scope)
+                       for q in parts), (branch, scope)
+    # AD's statistics and choice sit outside every branch
+    assert any("AD" in q and "frontier" in q
+               and not {"BS", "WD", "HP"} & set(q) for q in parts)
+
+
+def test_engine_run_host_spans(tmp_path):
+    """``engine.run`` marks its set-up, the fused call, the wait and the
+    readback with host spans on the profiler's clock, in that order."""
+    import jax
+    from jax.profiler import ProfileData
+
+    g = GRAPHS["rmat"]
+    engine.run(g, 0, engine.make_strategy("AD"), mode="fused")   # warm
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        engine.run(g, 0, engine.make_strategy("AD"), mode="fused")
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    pd = ProfileData.from_file(str(path))
+    names = ("engine.setup", "engine.dispatch", "engine.wait",
+             "engine.readback")
+    spans = sorted((ev.start_ns, ev.name) for plane in pd.planes
+                   if plane.name.startswith("/host:")
+                   for line in plane.lines for ev in line.events
+                   if ev.name in names)
+    assert [name for _, name in spans] == list(names) + ["engine.readback"]
