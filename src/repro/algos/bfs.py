@@ -18,7 +18,7 @@ from repro.core.multi_source import BatchRunResult
 
 
 def _unweighted(graph: CSRGraph) -> CSRGraph:
-    if graph.wt is None:
+    if not graph.weighted:
         return graph
     return CSRGraph(graph.row_ptr, graph.col, None,
                     graph.num_nodes, graph.num_edges, graph.max_degree)

@@ -27,7 +27,7 @@ def sssp(graph: CSRGraph, source: int = 0, strategy: str = "WD",
     (``delta=`` overrides the auto-tuned bucket width) — and
     ``async_shards=True`` relaxes the sharded halo-combine cadence
     (docs/scheduling.md)."""
-    assert graph.wt is not None, "SSSP needs a weighted graph"
+    assert graph.weighted, "SSSP needs a weighted graph"
     strat = make_strategy(strategy, **strategy_kwargs)
     return run(graph, source, strat, record_degrees=record_degrees,
                mode=mode, shards=shards, partition=partition,
@@ -40,7 +40,7 @@ def sssp_batch(graph: CSRGraph, sources, mode: str = "stepped",
                backend: str = "xla", schedule: str = "bsp",
                delta=None) -> BatchRunResult:
     """Shortest paths from K sources concurrently (dist is ``[K, N]``)."""
-    assert graph.wt is not None, "SSSP needs a weighted graph"
+    assert graph.weighted, "SSSP needs a weighted graph"
     return run_batch(graph, sources, mode=mode, shards=shards,
                      partition=partition, backend=backend,
                      schedule=schedule, delta=delta)

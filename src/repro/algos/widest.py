@@ -51,7 +51,7 @@ def reference_widest(graph: CSRGraph, source: int) -> np.ndarray:
     a max-heap on path width (the NetworkX-style reference)."""
     row_ptr = np.asarray(graph.row_ptr)
     col = np.asarray(graph.col)
-    wt = (np.ones(graph.num_edges, np.int64) if graph.wt is None
+    wt = (np.ones(graph.num_edges, np.int64) if not graph.weighted
           else np.asarray(graph.wt, np.int64))
     n = graph.num_nodes
     width = np.zeros(n, np.int64)

@@ -262,6 +262,8 @@ def measure(graph: CSRGraph, *, backend: str = "xla",
 
     from repro.core import fused, node_split
 
+    if backend == "pallas":
+        graph = graph.plain()        # whole weight tables (engine.run)
     degrees = np.asarray(graph.degrees)
     resolved = sched.resolved(degrees)
     dist0 = np.full(graph.num_nodes, np.iinfo(np.int32).max, np.int32)

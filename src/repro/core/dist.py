@@ -43,7 +43,7 @@ def partition_graph(g: CSRGraph, parts: int) -> PartitionedGraph:
     """Host-side 1-D range partition with per-shard padding."""
     row_ptr = np.asarray(g.row_ptr, np.int64)
     col = np.asarray(g.col)
-    wt = (np.asarray(g.wt) if g.wt is not None
+    wt = (np.asarray(g.wt) if g.weighted
           else np.ones(g.num_edges, np.int32))
     n = g.num_nodes
     n_loc = -(-n // parts)

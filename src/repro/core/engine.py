@@ -317,7 +317,12 @@ def run(graph: CSRGraph, source: int, strategy: StrategyBase, *,
     ``shards=``) lets every shard relax its local frontier to a local
     fixed point between halo combines instead of combining every chunk
     — same final values for idempotent operators, fewer collectives;
-    ``iterations`` then counts combine epochs (docs/scheduling.md)."""
+    ``iterations`` then counts combine epochs (docs/scheduling.md).
+
+    The edge layout is the graph's own (``CSRGraph.from_edges`` packs
+    weights that fit beside their heads, docs/architecture.md), except
+    under ``backend="pallas"``, whose kernels hold whole weight tables:
+    ``engine.setup`` then decodes the words once (``CSRGraph.plain``)."""
     if mode not in ("stepped", "fused"):
         raise ValueError(
             f"mode must be 'stepped' or 'fused', got {mode!r}")
@@ -348,6 +353,8 @@ def run(graph: CSRGraph, source: int, strategy: StrategyBase, *,
     # engine.readback around the fused call): no-ops unless a profiler runs
     t0 = time.perf_counter()
     with jax.profiler.TraceAnnotation("engine.setup"):
+        if backend == "pallas":
+            graph = graph.plain()
         state = strategy.setup(graph)
         splan = None
         dplan = None
@@ -543,6 +550,8 @@ def fixed_point(graph: CSRGraph, strategy: StrategyBase, init, *,
     _check_sharding(strategy, mode, shards)
     _check_backend(strategy, backend, shards)
     _check_schedule(strategy, schedule, delta, op, shards, async_shards)
+    if backend == "pallas":
+        graph = graph.plain()        # whole weight tables: see run()
     state = strategy.setup(graph)
     if isinstance(strategy, NodeSplitting):
         n_alloc = strategy.split_info.graph.num_nodes
@@ -626,7 +635,7 @@ def reference_distances(graph: CSRGraph, source: int) -> np.ndarray:
     import heapq
     row_ptr = np.asarray(graph.row_ptr)
     col = np.asarray(graph.col)
-    wt = (np.ones(graph.num_edges, np.int64) if graph.wt is None
+    wt = (np.ones(graph.num_edges, np.int64) if not graph.weighted
           else np.asarray(graph.wt, np.int64))
     n = graph.num_nodes
     dist = np.full(n, np.iinfo(np.int64).max)

@@ -109,8 +109,8 @@ from repro.core.operators import EdgeOp
 from repro.core.schedule import DEFAULT_SCHEDULE, Schedule
 from repro.core.strategies import (
     AdaptiveStrategy, EdgeBased, HierarchicalProcessing, NodeBased,
-    NodeSplitting, WorkloadDecomposition, _apply_relax, _edge_weight,
-    _merge_path_lanes, pallas_relax, relax_fn)
+    NodeSplitting, WorkloadDecomposition, _apply_relax, _merge_path_lanes,
+    _plain_tables, pallas_relax, relax_fn)
 from repro.core.worklist import prefix_sum
 
 #: traversals started, per kernel — incremented once per fused fixed-point
@@ -320,15 +320,15 @@ def _merge_path_relax(g: CSRGraph, dist, updated, work, cursor=None, *,
         with jax.named_scope("relax"):
             src_ids = jnp.arange(g.num_nodes, dtype=jnp.int32)
             prop, upd, _ = pallas_relax.wd_relax_lanes(
-                dist, prefix, exclusive, start, src_ids, g.col, g.wt,
-                cap_work=g.num_edges, op=op,
+                dist, prefix, exclusive, start, src_ids, g.col,
+                _plain_tables(g).wt, cap_work=g.num_edges, op=op,
                 **pallas_relax.tile_kwargs(sched))
             dist = pallas_relax.apply_proposal(dist, prop, op)
         return dist, updated | upd, total, (jnp.int32(1), jnp.int32(0),
                                             jnp.int32(g.num_edges))
     with jax.named_scope("lanemap"):
-        lanes = _merge_path_lanes(work, prefix, start - exclusive, g.col,
-                                  g.wt, num_edges=g.num_edges,
+        lanes = _merge_path_lanes(work, prefix, start - exclusive,
+                                  g.edge_pair, num_edges=g.num_edges,
                                   pad=_block_sizes(g.num_edges, backend)[-1])
     dist, updated = _relax_batch(dist, updated, total, lanes,
                                  cap=g.num_edges, op=op, backend=backend,
@@ -373,7 +373,7 @@ def _bs_step(g: CSRGraph, dist, mask, *,
         def lanes(lo, size):
             src = lax.dynamic_slice(order, (lo,), (size,))
             eidx = jnp.clip(base[src] + d, 0, g.num_edges - 1)
-            return src, g.col[eidx], _edge_weight(g, eidx)
+            return (src, *g.edge_pair(eidx))
         # frontier nodes with degree > d walk column d
         with jax.named_scope("frontier"):
             live = jnp.searchsorted(walking, -d,
@@ -481,10 +481,10 @@ def _ep_step(g: CSRGraph, edge_src, dist, mask, *,
         updated = jnp.zeros_like(mask)
         edges = jnp.sum(valid.astype(jnp.int32))
     with jax.named_scope("lanemap"):
-        w = _edge_weight(g, jnp.arange(g.num_edges, dtype=jnp.int32))
+        dst, w = g.edges()
     with jax.named_scope("relax"):
         dist, updated, _ = relax_fn(backend, sched)(
-            dist, updated, edge_src, g.col, w, valid, op=op)
+            dist, updated, edge_src, dst, w, valid, op=op)
     return dist, updated, edges, (jnp.int32(1), jnp.int32(0),
                                   jnp.int32(g.num_edges))
 

@@ -200,6 +200,8 @@ def run_batch(graph: CSRGraph, sources, *, max_iterations: int = 100000,
             "(docs/sharding.md)")
     from repro.core.engine import _check_backend, _check_schedule
     _check_backend(None, backend, shards)
+    if backend == "pallas":
+        graph = graph.plain()        # whole weight tables (engine.run)
     op = operators.resolve(op)
     _check_schedule(None, schedule, delta, op, shards, False)
     if schedule == "delta" and mode != "fused":

@@ -67,7 +67,7 @@ def split_graph(g: CSRGraph, mdt: int) -> SplitGraph:
     mdt = max(1, int(mdt))
     row_ptr = np.asarray(g.row_ptr, np.int64)
     col = np.asarray(g.col)
-    wt = None if g.wt is None else np.asarray(g.wt)
+    wt = np.asarray(g.wt) if g.weighted else None
     n = g.num_nodes
     deg = row_ptr[1:] - row_ptr[:-1]
 

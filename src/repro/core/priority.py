@@ -103,7 +103,7 @@ def auto_delta(graph: CSRGraph,
     into one bucket per distinct distance (and ``bucket_index`` would
     divide by zero)."""
     multiplier = max(1, int(multiplier))
-    if graph.wt is None or graph.num_edges == 0:
+    if not graph.weighted or graph.num_edges == 0:
         return multiplier
     mean = float(np.asarray(graph.wt).mean())
     return max(1, int(round(multiplier * mean)))
@@ -115,7 +115,7 @@ def _edge_subgraph(g: CSRGraph, keep: np.ndarray) -> CSRGraph:
     kept_before = np.concatenate([[0], np.cumsum(keep, dtype=np.int64)])
     row_ptr = kept_before[rp].astype(np.int32)
     col = np.asarray(g.col)[keep]
-    wt = None if g.wt is None else np.asarray(g.wt)[keep]
+    wt = np.asarray(g.wt)[keep] if g.weighted else None
     deg = row_ptr[1:] - row_ptr[:-1]
     return CSRGraph(
         row_ptr=jnp.asarray(row_ptr),
@@ -190,7 +190,7 @@ def plan_delta(strategy, state, graph: CSRGraph, *,
     delta = int(delta)
     if delta < 1:
         raise ValueError(f"delta must be >= 1, got {delta}")
-    if op.weight_additive and g.wt is not None and g.num_edges:
+    if op.weight_additive and g.weighted and g.num_edges:
         light = np.asarray(g.wt) <= delta
     else:
         light = np.ones(int(g.num_edges), bool)

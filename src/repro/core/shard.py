@@ -249,7 +249,7 @@ def partition(graph: CSRGraph, num_shards: int, *,
     bounds = partition_boundaries(graph, num_shards, method)
     rp = np.asarray(graph.row_ptr, np.int64)
     col = np.asarray(graph.col)
-    wt = None if graph.wt is None else np.asarray(graph.wt)
+    wt = np.asarray(graph.wt) if graph.weighted else None
 
     counts = np.diff(bounds)
     e_counts = rp[bounds[1:]] - rp[bounds[:-1]]
@@ -437,7 +437,8 @@ def _merge_path_local(sq: ShardedCSRGraph, dist, updated, gids, work,
         return (pallas_relax.apply_proposal(dist, prop, op), updated | upd,
                 total)
     lanes = _merge_path_lanes(
-        work, prefix, start - exclusive, sq.col, sq.wt,
+        work, prefix, start - exclusive,
+        lambda eidx: (sq.col[eidx], _local_weight(sq, eidx)),
         num_edges=sq.edges_per_shard,
         pad=_block_sizes(sq.edges_per_shard, backend)[-1], src_ids=gids)
     base = dist

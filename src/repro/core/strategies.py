@@ -91,9 +91,22 @@ BACKENDS = ("xla", "pallas")
 # ---------------------------------------------------------------------------
 
 def _edge_weight(g, eidx: jax.Array) -> jax.Array:
+    """Weights of COO edges ``eidx`` (ones when unweighted); a CSR graph
+    reads its edges through ``CSRGraph.edge_pair``."""
     if g.wt is not None:
         return g.wt[eidx]
     return jnp.ones(eidx.shape, jnp.int32)
+
+
+def _plain_tables(g: CSRGraph) -> CSRGraph:
+    """``g`` for a Pallas kernel that takes whole ``col``/``wt`` tables:
+    the plain layout, which ``engine`` hands every Pallas traversal
+    (``CSRGraph.plain``, decoded once before the loop)."""
+    if g.wt_shift is not None:
+        raise ValueError(
+            "backend='pallas' reads plain weight tables; pass "
+            "graph.plain() (engine.run and run_batch do)")
+    return g
 
 
 def _apply_relax(dist, updated, src, dst, w, valid, *,
@@ -160,9 +173,8 @@ def bs_relax(g: CSRGraph, dist, frontier, *, cap: int,
         d, dist, updated = c
         valid = mask & (d < deg)
         eidx = jnp.clip(base + d, 0, g.num_edges - 1)
-        dist, updated, _ = relax(
-            dist, updated, f, g.col[eidx], _edge_weight(g, eidx), valid,
-            op=op)
+        dst, w = g.edge_pair(eidx)
+        dist, updated, _ = relax(dist, updated, f, dst, w, valid, op=op)
         return d + 1, dist, updated
 
     _, dist, updated = jax.lax.while_loop(
@@ -218,7 +230,7 @@ def ep_push_unchunked(row_ptr, improve, dst, total, *, cap_out: int):
 # WD — workload decomposition (merge-path over the frontier's edges)
 # ---------------------------------------------------------------------------
 
-def _merge_path_lanes(work, prefix, base, col, wt, *, num_edges: int,
+def _merge_path_lanes(work, prefix, base, edge_pair, *, num_edges: int,
                       pad: int, src_ids=None):
     """``lanes(lo, size) -> (src, dst, w)`` of merge-path lanes
     ``lo .. lo+size-1`` — the XLA lowering of the paper's
@@ -231,7 +243,9 @@ def _merge_path_lanes(work, prefix, base, col, wt, *, num_edges: int,
     at most ``size`` slots from the first one it touches, so ranking the
     block takes a scatter + scan over ``size`` entries instead of a
     binary search per lane.  ``src_ids`` maps node -> source id (default:
-    the node itself); ``pad`` is the largest ``size`` asked for."""
+    the node itself); ``pad`` is the largest ``size`` asked for.
+    ``edge_pair(eidx) -> (dst, w)`` reads the edges
+    (``CSRGraph.edge_pair``)."""
     n = work.shape[0]
     big = jnp.iinfo(jnp.int32).max
     live = work > 0
@@ -250,9 +264,8 @@ def _merge_path_lanes(work, prefix, base, col, wt, *, num_edges: int,
         node = node_of[s]
         k = lo + jnp.arange(size, dtype=jnp.int32)
         eidx = jnp.clip(base[node] + k, 0, num_edges - 1)
-        w = (wt[eidx] if wt is not None
-             else jnp.ones((size,), jnp.int32))
-        return (node if src_ids is None else src_ids[node]), col[eidx], w
+        src = node if src_ids is None else src_ids[node]
+        return (src, *edge_pair(eidx))
     return lanes
 
 
@@ -267,11 +280,11 @@ def _merge_relax(g: CSRGraph, dist, f, start, work, *, cap_work: int,
     updated = jnp.zeros((dist.shape[0],), jnp.bool_)
     if backend == "pallas":
         prop, upd, _ = pallas_relax.wd_relax_lanes(
-            dist, prefix, exclusive, start, f, g.col, g.wt,
+            dist, prefix, exclusive, start, f, g.col, _plain_tables(g).wt,
             cap_work=cap_work, op=op, **pallas_relax.tile_kwargs(sched))
         return pallas_relax.apply_proposal(dist, prop, op), updated | upd
     src, dst, w = _merge_path_lanes(
-        work, prefix, start - exclusive, g.col, g.wt,
+        work, prefix, start - exclusive, g.edge_pair,
         num_edges=g.num_edges, pad=cap_work, src_ids=f)(0, cap_work)
     dist, updated, _ = _apply_relax(
         dist, updated, src, dst, w,

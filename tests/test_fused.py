@@ -6,11 +6,13 @@ same relaxed-edge total — while issuing exactly one jit dispatch for the
 whole traversal (and recompiling nothing when shapes repeat).
 """
 
+import re
+
 import numpy as np
 import pytest
 
 from repro.algos import bfs, sssp_batch
-from repro.core import engine, fused
+from repro.core import engine, fused, strategies
 from repro.core.graph import CSRGraph, INF
 from repro.data import (erdos_renyi_graph, graph500_graph, rmat_graph,
                         road_grid_graph)
@@ -32,26 +34,44 @@ def graphs():
 GRAPHS = graphs()
 
 
-def _run_pair(g, strategy, source=0):
-    stepped = engine.run(g, source, engine.make_strategy(strategy))
+def _run_pair(g, strategy, source=0, **kw):
+    stepped = engine.run(g, source, engine.make_strategy(strategy), **kw)
     fusedr = engine.run(g, source, engine.make_strategy(strategy),
-                        mode="fused")
+                        mode="fused", **kw)
     return stepped, fusedr
 
 
+def numbers(r):
+    """Everything a traversal reports that the edge layout must not
+    move."""
+    return (np.asarray(r.dist).tolist(), r.iterations, r.edges_relaxed,
+            r.relax_batches, r.lanes_run)
+
+
 # ---------------------------------------------------------------------------
-# fused ≡ stepped on the graph zoo
+# fused ≡ stepped on the graph zoo, packed ≡ plain edge layout
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("gname", list(GRAPHS))
+PARITY = ([(gname, "shortest_path") for gname in GRAPHS]
+          + [("rmat", "widest_path"), ("road", "widest_path")])
+
+
+@pytest.mark.parametrize("packed", [True, False])
+@pytest.mark.parametrize("gname,op", PARITY)
 @pytest.mark.parametrize("strategy", STRATEGIES)
-def test_fused_matches_stepped(gname, strategy):
-    g = GRAPHS[gname]
-    stepped, fusedr = _run_pair(g, strategy)
+def test_fused_matches_stepped(gname, op, strategy, packed):
+    # from_edges packs the suite's weights; plain() decodes them
+    g = GRAPHS[gname] if packed else GRAPHS[gname].plain()
+    assert (g.wt_shift is not None) == packed
+    stepped, fusedr = _run_pair(g, strategy, op=op)
     np.testing.assert_array_equal(fusedr.dist, stepped.dist)
     assert fusedr.iterations == stepped.iterations
     assert fusedr.edges_relaxed == stepped.edges_relaxed
     assert stepped.mode == "stepped" and fusedr.mode == "fused"
+    if packed:
+        other = engine.run(g.plain(), 0, engine.make_strategy(strategy),
+                           mode="fused", op=op)
+        assert numbers(fusedr) == numbers(other)
 
 
 @pytest.mark.parametrize("strategy", ["BS", "WD", "AD"])
@@ -133,9 +153,10 @@ def test_fused_mode_validation():
 # batched multi-source fused loop
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("packed", [True, False])
 @pytest.mark.parametrize("gname", ["rmat", "road"])
-def test_batch_fused_matches_stepped(gname):
-    g = GRAPHS[gname]
+def test_batch_fused_matches_stepped(gname, packed):
+    g = GRAPHS[gname] if packed else GRAPHS[gname].plain()
     sources = [0, 3, 17, 42]
     stepped = sssp_batch(g, sources)
     fusedb = sssp_batch(g, sources, mode="fused")
@@ -146,6 +167,11 @@ def test_batch_fused_matches_stepped(gname):
     for i, s in enumerate(sources):
         single = engine.run(g, s, engine.make_strategy("WD"))
         np.testing.assert_array_equal(fusedb.dist[i], single.dist)
+    if packed:
+        other = sssp_batch(g.plain(), sources, mode="fused")
+        np.testing.assert_array_equal(fusedb.dist, other.dist)
+        assert ((fusedb.iterations, fusedb.edges_relaxed)
+                == (other.iterations, other.edges_relaxed))
 
 
 def test_batch_fused_single_dispatch():
@@ -359,6 +385,77 @@ def test_fused_ad_lowering_names_its_scopes():
     # AD's statistics and choice sit outside every branch
     assert any("AD" in q and "frontier" in q
                and not {"BS", "WD", "HP"} & set(q) for q in parts)
+
+
+def _lowered(g, kernel, op="shortest_path", backend="xla"):
+    """The fused loop's StableHLO for ``kernel`` on ``g``."""
+    import jax.numpy as jnp
+
+    from repro.core import operators
+
+    op = operators.resolve(op)
+    strat = engine.make_strategy(kernel)
+    plan = fused._plan(strat, strat.setup(g), g)
+    dist = jnp.full((g.num_nodes,), op.identity, op.dtype)
+    mask = jnp.zeros((g.num_nodes,), jnp.bool_).at[0].set(True)
+    return fused._fixed_point.lower(
+        g, jnp.zeros((1,), jnp.int32), dist, mask, kernel=kernel,
+        max_iterations=100, op=op, backend=backend, **plan.static).as_text()
+
+
+def edge_gathers(text, e):
+    """Gathers in lowered StableHLO whose operand is an ``[e]`` int32
+    array: the per-lane reads of ``col`` and the weights."""
+    return len(re.findall(
+        rf'"stablehlo\.gather".*: \(tensor<{e}xi32>, ', text))
+
+
+@pytest.mark.parametrize("kernel", ["BS", "WD", "HP", "AD"])
+def test_packed_lane_maps_gather_once(kernel):
+    """Every lane map of the packed layout reads an edge with ONE gather
+    of its word where the plain layout gathers ``col`` and the weight."""
+    g = GRAPHS["g500"]
+    assert g.num_edges not in (g.num_nodes, g.num_nodes + 1)
+    packed = edge_gathers(_lowered(g, kernel), g.num_edges)
+    unpacked = edge_gathers(_lowered(g.plain(), kernel), g.num_edges)
+    assert packed > 0 and unpacked == 2 * packed
+
+
+@pytest.mark.parametrize("mode,module,name", [
+    ("fused", fused, "_fixed_point"),
+    ("stepped", strategies, "wd_relax"),
+])
+def test_pallas_tables_decoded_before_the_loop(mode, module, name,
+                                              monkeypatch):
+    """The Pallas kernels hold whole ``col``/weight tables: ``engine.run``
+    hands them the plain layout, decoded once in its set-up, so no
+    Pallas program decodes packed words inside the loop."""
+    g = GRAPHS["rmat"]
+    assert g.wt_shift is not None
+    seen = []
+    inner = getattr(module, name)
+
+    def spy(graph, *args, **kw):
+        seen.append(graph.wt_shift)
+        return inner(graph, *args, **kw)
+
+    monkeypatch.setattr(module, name, spy)
+    got = engine.run(g, 0, engine.make_strategy("WD"), mode=mode,
+                     backend="pallas")
+    monkeypatch.setattr(module, name, inner)
+    want = engine.run(g, 0, engine.make_strategy("WD"), mode=mode)
+    assert seen and set(seen) == {None}
+    np.testing.assert_array_equal(got.dist, want.dist)
+    assert (got.iterations, got.edges_relaxed) == (want.iterations,
+                                                   want.edges_relaxed)
+    # the loop's lowering: no [E]-wide decode (a shift of the words)
+    text = _lowered(g.plain(), "WD", backend="pallas")
+    assert not re.search(
+        rf"stablehlo\.shift_right_arithmetic.*tensor<{g.num_edges}xi32>",
+        text)
+    # and a packed graph handed to the Pallas tables is refused
+    with pytest.raises(ValueError, match="plain"):
+        _lowered(g, "WD", backend="pallas")
 
 
 def test_engine_run_host_spans(tmp_path):

@@ -99,3 +99,58 @@ def test_single_node_graph():
                             np.zeros(0, np.int32) if g.wt is None else g.wt,
                             1, 0, 0), 0, strategy=s)
         assert res.dist[0] == 0
+
+
+# ---------------------------------------------------------------------------
+# the packed edge word: (w << b) | dst in one int32, b = bits of a head
+# ---------------------------------------------------------------------------
+
+#: (nodes, largest weight or None, packs): the weights fit beside a head
+#: of b = max(1, (nodes - 1).bit_length()) bits while below 2**(31 - b)
+PACKING = [
+    (512, 2 ** 22 - 1, True),        # b = 9: the largest weight that fits
+    (512, 2 ** 22, False),           # one past it
+    (512, -1, False),                # a negative weight
+    (512, None, False),              # unweighted
+    (1024, 2 ** 21 - 1, True),       # nodes a power of two: b = 10
+    (1024, 2 ** 21, False),
+    (1, 2 ** 30 - 1, True),          # one node: b = 1
+    (1, 2 ** 30, False),
+]
+
+
+@pytest.mark.parametrize("n,w_top,packs", PACKING)
+def test_packed_edge_word_layout(n, w_top, packs):
+    rng = np.random.default_rng(n)
+    e = 40
+    src = rng.integers(0, n, e)
+    dst = rng.integers(0, n, e)
+    dst[0] = n - 1                   # the largest head
+    wt = None
+    if w_top is not None:
+        wt = rng.integers(0, 100, e)
+        wt[e // 2] = w_top
+    g = CSRGraph.from_edges(src, dst, wt, n)
+    b = max(1, (n - 1).bit_length())
+    assert g.wt_shift == (b if packs else None)
+    assert g.weighted == (wt is not None)
+    order = np.argsort(src, kind="stable")
+    np.testing.assert_array_equal(np.asarray(g.col), dst[order])
+    if wt is None:
+        assert g.wt is None and g.wt_word is None
+    else:
+        got = np.asarray(g.wt)
+        assert got.dtype == np.int32
+        np.testing.assert_array_equal(got, wt[order])
+    if packs:
+        np.testing.assert_array_equal(
+            np.asarray(g.wt_word) & ((1 << b) - 1), np.asarray(g.col))
+    assert g.device_bytes() == 4 * (n + 1 + e * (1 + g.weighted))
+    plain = g.plain()
+    assert plain.wt_shift is None and (plain is g) == (not packs)
+    assert plain.device_bytes() == g.device_bytes()
+    if w_top is not None and w_top >= 0:
+        # the top weight decodes exactly inside the fused loop too
+        res = sssp(g, int(src[e // 2]), strategy="WD", mode="fused")
+        np.testing.assert_array_equal(
+            res.dist, engine.reference_distances(plain, int(src[e // 2])))

@@ -45,6 +45,9 @@ N_SHARDS = min(len(jax.devices()), 4)
 ROAD = road_grid_graph(side=12, weighted=True, seed=5)
 #: the low-diameter skewed input where BSP was already fine
 RMAT = rmat_graph(scale=8, edge_factor=6, weighted=True, seed=5)
+#: ROAD in the plain edge layout (``from_edges`` packs ROAD's weights
+#: beside its heads; the constructor keeps them in their own array)
+ROAD_PLAIN = ROAD.plain()
 
 #: documented work bound: delta-stepping may re-relax light edges while
 #: closing a bucket, but the light closure touches each bucket's frontier
@@ -92,18 +95,32 @@ def test_delta_matches_dijkstra_oracle():
         np.testing.assert_array_equal(r.dist, ref)
 
 
+def _delta_numbers(r):
+    return (r.dist.tolist(), r.iterations, r.relax_rounds, r.edges_relaxed,
+            r.delta)
+
+
+@pytest.mark.parametrize("packed", [True, False])
 @pytest.mark.parametrize("op", MONOTONE_OPS)
-def test_delta_stepped_equals_fused(op):
+def test_delta_stepped_equals_fused(op, packed):
     """Stepped and fused delta are the same schedule: bit-identical
-    dist, equal epochs, relax rounds and edge totals."""
-    stepped = engine.run(ROAD, 0, _strategy("WD"), op=op, schedule="delta")
-    fused = engine.run(ROAD, 0, _strategy("WD"), op=op, mode="fused",
+    dist, equal epochs, relax rounds and edge totals — in the packed
+    edge layout and the plain one alike (every ROAD edge is light here,
+    so the light graph aliases the packed graph)."""
+    g = ROAD if packed else ROAD_PLAIN
+    stepped = engine.run(g, 0, _strategy("WD"), op=op, schedule="delta")
+    fused = engine.run(g, 0, _strategy("WD"), op=op, mode="fused",
                        schedule="delta")
     np.testing.assert_array_equal(stepped.dist, fused.dist)
     assert stepped.iterations == fused.iterations
     assert stepped.relax_rounds == fused.relax_rounds
     assert stepped.edges_relaxed == fused.edges_relaxed
     assert stepped.delta == fused.delta
+    assert (g.wt_shift is not None) == packed
+    if packed:
+        other = engine.run(ROAD_PLAIN, 0, _strategy("WD"), op=op,
+                           mode="fused", schedule="delta")
+        assert _delta_numbers(fused) == _delta_numbers(other)
 
 
 def test_delta_pallas_backend_parity():
@@ -193,8 +210,12 @@ def test_degenerate_delta_plan_aliases_graph():
     plan = priority.plan_delta(strat, state, ROAD, delta=2 * int(INF))
     assert not plan.heavy
     assert plan.light.col is ROAD.col
+    assert ROAD.wt_shift is not None
+    assert plan.light.wt_shift == ROAD.wt_shift
     split = priority.plan_delta(strat, state, ROAD, delta=1)
     assert split.heavy
+    # the split graphs keep plain weights
+    assert split.light.wt_shift is None and split.heavy_graph.wt_shift is None
     assert (split.light.num_edges + split.heavy_graph.num_edges
             == ROAD.num_edges)
 

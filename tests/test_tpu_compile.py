@@ -14,14 +14,20 @@ tests hold
   compiles, and shapes it puts over, by their tables or by the chunk
   loop's scratch alone, Mosaic refuses too;
 * the sharded fused fixed point over a 4-device mesh, whose replicated
-  rank-0 outputs need ``P()`` out_specs.
+  rank-0 outputs need ``P()`` out_specs;
+* the fused AD loop on a graph in the packed edge layout: the chip's
+  compiler keeps one gather of the ``[E]`` word per lane map where the
+  plain layout has two (``col`` and the weights), and does not copy
+  the word's gather into each of its consumers.
 
 The topology is described inside a module-scoped fixture, never at
 import time: only one process at a time may load the TPU library, and
 every test worker imports this file.
 """
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -30,8 +36,9 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import operators, shard
-from repro.core.schedule import DEFAULT_SCHEDULE
+from repro.core import fused, operators, shard
+from repro.core.graph import CSRGraph
+from repro.core.schedule import DEFAULT_SCHEDULE, default_schedule
 from repro.kernels import find_offsets, relax
 
 #: the suite rmat graph of chip_smoke.py phase 3:
@@ -169,3 +176,42 @@ def test_sharded_fused_wd_compiles_on_four_chips(topo, no_cache):
     mem = compiled.memory_analysis()
     # each device holds its own shard's block, not the whole stack
     assert mem.argument_size_in_bytes < 2 * (s * e_loc * 4)
+
+
+def _lanemap_edge_gathers(text, e):
+    """Instructions of optimized HLO named ``.../lanemap/gather`` that
+    read an ``s32[e]`` operand (a gather, or the fusion around one)."""
+    shape = dict(re.findall(r"^\s*(?:ROOT )?%([\w.-]+) = (\S+) ", text,
+                            re.M))
+    count = 0
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.-]+ = \S+ (?:fusion|gather)\((.*?)\)",
+                     line)
+        if not m or not re.search(r'op_name="[^"]*lanemap/gather"', line):
+            continue
+        operands = re.findall(r"%([\w.-]+)", m.group(1))
+        count += any(shape.get(o, "").startswith(f"s32[{e}]{{")
+                     for o in operands)
+    return count
+
+
+def test_packed_fused_ad_gathers_each_edge_once(one_chip, no_cache):
+    """The AD loop at a small shape, packed and plain: every lane map of
+    the packed layout keeps one ``[E]`` gather where the plain layout
+    keeps two."""
+    n, e = 4096, 49157
+    row_ptr, edges = _spec(one_chip, (n + 1,)), _spec(one_chip, (e,))
+    plain = CSRGraph(row_ptr, edges, edges, n, e, 1000)
+    # the same shapes in the packed layout: a head takes 12 bits
+    packed = CSRGraph.tree_unflatten((n, e, 1000, 12),
+                                     (row_ptr, edges, edges))
+    sched = dataclasses.replace(default_schedule("AD"), mdt=64)
+    counts = []
+    for g in (plain, packed):
+        text = fused._fixed_point.lower(
+            g, _spec(one_chip, (1,)), _spec(one_chip, (n,)),
+            _spec(one_chip, (n,), jnp.bool_), kernel="AD",
+            max_iterations=1000, sched=sched, op=operators.shortest_path,
+            backend="xla").compile().as_text()
+        counts.append(_lanemap_edge_gathers(text, e))
+    assert counts[1] > 0 and counts[0] == 2 * counts[1]
