@@ -9,24 +9,30 @@ chips the cell asks for, turns on JAX's persistent compilation cache
    generator (``bench/gen/<generator>.py``, named by the configuration);
    the weighted structure comes from the configuration, and ``--seed``
    draws the node labels;
-2. builds the program's CSR (``CSRGraph.from_edges``), the set-up layer;
-3. warms up with one traversal from a root outside the window's list;
-4. runs a closed loop, one client, back to back through the traffic
-   mix's public entry (``repro.algos.sssp`` / ``bfs``) from the
-   configuration's stream of search keys, and closes the window at the
-   first completion at or after ``--seconds``;
-5. with ``--trace 1``, profiles the first whole query of the window and
-   reduces the device trace (:mod:`bench.tracing`);
-6. compares a seeded sample of the window's distance rows with the
-   plain reference (:mod:`bench.reference`) once the window has closed;
+2. builds the program's CSR (``CSRGraph.from_edges``), the set-up layer,
+   once for each kind of query the traffic mix runs (weighted,
+   unweighted or both);
+3. warms up: the loop's own ``warmup(ctx)``, or :func:`warm_up`, one
+   traversal of each kind from the first key of the mix's root stream;
+4. hands the window to the traffic mix's loop
+   (``bench/loops/<loop>.py``, ``closed_one_client`` by default), which
+   drives the system under test through a :class:`Ctx` and returns a
+   :class:`LoopResult`;
+5. with ``--trace 1``, reduces the device trace of the sub-window the
+   loop profiled (:mod:`bench.tracing`), naming idle gaps by the loop's
+   ``HOST_SPANS``;
+6. compares the sample of the window's distance rows that the loop
+   offered (:meth:`Ctx.offer`) with the plain reference
+   (:mod:`bench.reference`) of each row's kind, once the window has
+   closed;
 7. prints one JSON line: ``correct``, ``attempted``, ``failed``, the
    cell's metrics (each read by ``bench/metrics/<name>.py``),
    ``device``, and last ``checks``, every number compared beside its
    limit.
 
-Everything that belongs to one configuration, traffic mix or metric is a
-file of its own that this module finds by name; adding one edits
-nothing here.
+Everything that belongs to one configuration, traffic mix, loop, root
+stream or metric is a file of its own that this module finds by name;
+adding one edits nothing here.
 """
 
 from __future__ import annotations
@@ -147,27 +153,39 @@ def peak_bytes() -> Optional[int]:
 
 # -- traffic -------------------------------------------------------------------
 
-#: traversals before the window, from a root outside the window's list
+#: keys at the head of the mix's root stream that :func:`warm_up` uses
 WARMUP_QUERIES = 1
-#: whole queries at the start of the window that ``--trace 1`` profiles
-TRACED_QUERIES = 1
 #: distance rows of the window compared with the reference
 CHECK_SAMPLE = 16
+#: the loop of a traffic mix that names none
+DEFAULT_LOOP = "closed_one_client"
 
 
-def search_keys(degrees: np.ndarray, labels: np.ndarray,
-                graph_seed: int) -> np.ndarray:
-    """Graph500's search keys: the nodes of degree >= 1 in a uniform
-    order, so no key repeats inside a run.  The order is drawn over the
-    structure's nodes from the configuration's ``graph_seed`` and mapped
-    through the seed's ``labels``: every seed runs the same structural
-    keys in the same order, so ``--seed`` does not change which roots a
-    window holds."""
-    rng = np.random.default_rng([graph_seed, 0x5EA4C4])
-    return labels[rng.permutation(np.flatnonzero(degrees[labels] > 0))]
+def kinds(traffic: dict) -> tuple:
+    """The kinds of query a mix runs, as ``weighted`` flags: its
+    ``"weighted"`` is ``true``, ``false`` or ``"both"``."""
+    w = traffic["weighted"]
+    if w == "both":
+        return (True, False)
+    if isinstance(w, bool):
+        return (w,)
+    raise ValueError(f"traffic {traffic.get('name')!r}: \"weighted\" is "
+                     f"true, false or \"both\", not {w!r}")
 
 
-ROOT_STREAMS = {"graph500_search_keys": search_keys}
+def entry_spec(traffic: dict, weighted: bool) -> str:
+    """The entry of a mix for one kind of query: ``"entry"`` is one
+    ``"module:function"`` for every kind, or an object keyed
+    ``"weighted"`` / ``"unweighted"``."""
+    e = traffic["entry"]
+    if isinstance(e, dict):
+        return e["weighted" if weighted else "unweighted"]
+    return e
+
+
+def load_loop(name: str):
+    """``bench/loops/<name>.py``."""
+    return load_module(BENCH / "loops" / f"{name}.py")
 
 
 def resolve_entry(spec: str) -> Callable:
@@ -184,6 +202,24 @@ class Query:
     edges: int                # CSR edges leaving them (TEPS numerator)
     relaxed: int              # the engine's own RunResult.edges_relaxed
     iterations: int
+    weighted: bool = False    # its kind, and so its reference
+    t_submit: float = 0.0     # seconds from the window's start
+    t_done: float = 0.0
+    cached: bool = False      # answered from a cache, with no traversal
+
+
+@dataclasses.dataclass
+class LoopResult:
+    """What a loop's ``run(ctx)`` returns.  Every request of the window
+    it counts in ``attempted`` is completed (in ``queries``), failed, or
+    still queued when the window closes."""
+    queries: list             # [Query] completed in the window, in order
+    start: float              # time.perf_counter() at the window's start
+    window_s: float
+    traced: list              # [Query] inside the traced sub-window
+    attempted: int
+    failed: int               # requests refused or lost by the loop
+    counters: dict = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -200,6 +236,96 @@ class Run:
     peaks: Optional[dict]     # bench/peaks.json entry of the device
     trace: Optional[dict]     # bench.tracing.reduce() of the traced queries
     traced: list              # [Query] inside the traced sub-window
+    counters: dict            # LoopResult.counters
+
+
+class Ctx:
+    """What a loop (``bench/loops/<loop>.py``) is handed: the system
+    under test, the run's parameters, and the harness's hooks.
+
+    ``graphs`` and ``entries`` are keyed by the ``weighted`` flag of
+    each kind the mix runs (:func:`kinds`); ``entries[w](graphs[w],
+    root, **kwargs)`` is one traversal, returning a ``RunResult``."""
+
+    def __init__(self, *, graphs: dict, entries: dict, kwargs: dict,
+                 traffic: dict, cfg: dict, degrees: np.ndarray,
+                 labels: np.ndarray, seed: int, seconds: float,
+                 trace: bool):
+        self.graphs = graphs
+        self.entries = entries
+        self.kwargs = kwargs
+        self.traffic = traffic
+        self.cfg = cfg
+        self.degrees = degrees
+        self.labels = labels
+        self.seed = seed
+        self.seconds = seconds
+        self.trace = trace
+        #: keys at the head of the mix's root stream that the warm-up
+        #: used; a loop that relies on :func:`warm_up` starts after them
+        self.warmup_keys = 0
+        self.sample: list = []               # reservoir of (Query, dist)
+        self._offered = 0
+        self._rng = np.random.default_rng([seed, 0xC4EC])
+        self._keys: dict = {}
+        self._trace_dir = tempfile.TemporaryDirectory() if trace else None
+        self._span = None
+        self._traced = False
+
+    def keys(self, stream: str) -> np.ndarray:
+        """``keys(degrees, labels, graph_seed, traffic)`` of
+        ``bench/roots/<stream>.py``, drawn once per run."""
+        if stream not in self._keys:
+            mod = load_module(BENCH / "roots" / f"{stream}.py")
+            self._keys[stream] = mod.keys(self.degrees, self.labels,
+                                          self.cfg["graph_seed"],
+                                          self.traffic)
+        return self._keys[stream]
+
+    def offer(self, query: Query, dist: np.ndarray) -> None:
+        """Offer a completed query's distance row to the sample that is
+        compared with the reference: a reservoir of
+        :data:`CHECK_SAMPLE` rows, drawn from the seed."""
+        i = self._offered
+        self._offered += 1
+        if len(self.sample) < CHECK_SAMPLE:
+            self.sample.append((query, dist))
+        else:
+            j = int(self._rng.integers(0, i + 1))
+            if j < CHECK_SAMPLE:
+                self.sample[j] = (query, dist)
+
+    def trace_on(self) -> None:
+        """With ``--trace 1``, start the profiler and the
+        :data:`bench.tracing.WINDOW_SPAN` span; once per run."""
+        if not self.trace or self._traced:
+            return
+        import jax
+        jax.profiler.start_trace(self._trace_dir.name,
+                                 profiler_options=_profile_options())
+        self._span = jax.profiler.TraceAnnotation(tracing.WINDOW_SPAN)
+        self._span.__enter__()
+        self._traced = True
+
+    def trace_off(self) -> None:
+        """Close the span and stop the profiler, if they run."""
+        if self._span is None:
+            return
+        import jax
+        self._span.__exit__(None, None, None)
+        self._span = None
+        jax.profiler.stop_trace()
+
+
+def warm_up(ctx: Ctx) -> None:
+    """The warm-up of a loop that defines none: one traversal of each
+    kind from each of the first :data:`WARMUP_QUERIES` keys of the mix's
+    root stream, which the window then skips (``ctx.warmup_keys``)."""
+    for root in ctx.keys(ctx.traffic["roots"])[:WARMUP_QUERIES].tolist():
+        for w, g in ctx.graphs.items():
+            reached_edges(np.asarray(
+                ctx.entries[w](g, root, **ctx.kwargs).dist), ctx.degrees)
+    ctx.warmup_keys = WARMUP_QUERIES
 
 
 class CompileLog:
@@ -265,116 +391,86 @@ def _run(c: Cell, cfg: dict, seed: int, seconds: float, trace: bool,
     _note(f"generate_s={time.perf_counter() - t0:.3f} nodes={n} "
           f"arcs={len(src)}")
     degrees = np.bincount(src, minlength=n).astype(np.int64)
-    weighted = bool(traffic["weighted"])
+    ks = kinds(traffic)
 
     t0 = time.perf_counter()
-    g = CSRGraph.from_edges(src, dst, wt if weighted else None, n)
-    jax.block_until_ready([g.row_ptr, g.col, g.wt])
+    graphs = {}
+    for w in ks:
+        g = CSRGraph.from_edges(src, dst, wt if w else None, n)
+        jax.block_until_ready([g.row_ptr, g.col, g.wt])
+        graphs[w] = g
     csr_build_s = time.perf_counter() - t0
     _note(f"csr_build_s={csr_build_s:.3f}")
 
     if entry_factory is None:
-        entry = resolve_entry(traffic["entry"])
+        entries = {w: resolve_entry(entry_spec(traffic, w)) for w in ks}
     else:
-        entry = entry_factory(src, dst, wt if weighted else None, n)
-    kwargs = traffic.get("kwargs", {})
-    keys = iter(ROOT_STREAMS[traffic["roots"]](
-        degrees, labels, cfg["graph_seed"]).tolist())
+        entries = {w: entry_factory(src, dst, wt if w else None, n)
+                   for w in ks}
+    loop = load_loop(traffic.get("loop", DEFAULT_LOOP))
+    ctx = Ctx(graphs=graphs, entries=entries,
+              kwargs=traffic.get("kwargs", {}), traffic=traffic, cfg=cfg,
+              degrees=degrees, labels=labels, seed=seed, seconds=seconds,
+              trace=trace)
 
     t0 = time.perf_counter()
-    for _ in range(WARMUP_QUERIES):
-        reached_edges(np.asarray(entry(g, next(keys), **kwargs).dist),
-                      degrees)
+    getattr(loop, "warmup", warm_up)(ctx)
     _note(f"warmup_s={time.perf_counter() - t0:.3f} "
           f"compiles={len(log.times)}")
-
-    sample_k = CHECK_SAMPLE
-    rng = np.random.default_rng([seed, 0xC4EC])
-    sample: list = []                          # reservoir of (Query, dist)
-    queries: list = []
-    n_traced = TRACED_QUERIES if trace else 0
-    trace_dir = tempfile.TemporaryDirectory() if trace else None
-    traced_span = None
     setup_s = time.perf_counter() - t_start
     _note(f"setup_s={setup_s:.3f}")
 
-    w0 = time.perf_counter()
-    while True:
-        i = len(queries)
-        if i == 0 and n_traced:
-            jax.profiler.start_trace(trace_dir.name,
-                                     profiler_options=_profile_options())
-            traced_span = jax.profiler.TraceAnnotation(tracing.WINDOW_SPAN)
-            traced_span.__enter__()
-        root_v = next(keys, None)
-        if root_v is None:           # every search key used: close early
-            break
-        q0 = time.perf_counter()
-        with jax.profiler.TraceAnnotation("query"):
-            r = entry(g, root_v, **kwargs)
-            dist = np.asarray(r.dist)
-        q1 = time.perf_counter()
-        with jax.profiler.TraceAnnotation("tally"):
-            reached, edges = reached_edges(dist, degrees)
-        q = Query(root=root_v, ms=(q1 - q0) * 1e3, reached=reached,
-                  edges=edges, relaxed=int(r.edges_relaxed),
-                  iterations=int(r.iterations))
-        queries.append(q)
-        if len(sample) < sample_k:
-            sample.append((q, dist))
-        else:
-            j = int(rng.integers(0, i + 1))
-            if j < sample_k:
-                sample[j] = (q, dist)
-        if n_traced and i + 1 == n_traced:
-            traced_span.__exit__(None, None, None)
-            jax.profiler.stop_trace()
-        if time.perf_counter() - w0 >= seconds:
-            break
-    w1 = time.perf_counter()
-    if n_traced and len(queries) < n_traced:
-        traced_span.__exit__(None, None, None)
-        jax.profiler.stop_trace()
-    window_compiles = log.between(w0, w1)
+    res = loop.run(ctx)
+    ctx.trace_off()
+    window_compiles = log.between(res.start, res.start + res.window_s)
     peak = peak_bytes()
-    del g, entry
+    graphs.clear()
+    entries.clear()
+    del g
 
     summary = None
     if trace:
-        summary = tracing.reduce(tracing.load(trace_dir.name))
-        trace_dir.cleanup()
+        summary = tracing.reduce(tracing.load(ctx._trace_dir.name),
+                                 getattr(loop, "HOST_SPANS", ()))
+        ctx._trace_dir.cleanup()
 
-    _note(f"window_s={w1 - w0:.3f} queries={len(queries)} "
-          f"query_ms={[round(q.ms, 1) for q in queries]}")
+    queued = res.attempted - len(res.queries) - res.failed
+    _note(f"window_s={res.window_s:.3f} queries={len(res.queries)} "
+          f"queued={queued} "
+          f"query_ms={[round(q.ms, 1) for q in res.queries]}")
 
-    # the plain reference, once the window has closed
+    # the plain reference of each row's kind, once the window has closed
     t0 = time.perf_counter()
-    ref = reference.Reference(src, dst, wt if weighted else None, n)
+    refs: dict = {}
     mismatches = []
-    for q, dist in sample:
-        want = ref.distances(q.root)
+    for q, dist in ctx.sample:
+        if q.weighted not in refs:
+            refs[q.weighted] = reference.Reference(
+                src, dst, wt if q.weighted else None, n)
+        want = refs[q.weighted].distances(q.root)
         mismatches.append(int(np.count_nonzero(dist != want))
                           if dist.shape == want.shape else n)
     dist_mismatch = int(sum(mismatches))
     _note(f"reference_s={time.perf_counter() - t0:.3f}")
-    failed = int(sum(m > 0 for m in mismatches))
+    failed = res.failed + int(sum(m > 0 for m in mismatches))
     checks = {"dist_mismatch": {"value": dist_mismatch, "limit": 0},
-              "queries_compared": {"value": len(sample), "limit": 1}}
-    correct = dist_mismatch <= 0 and len(sample) >= 1
+              "queries_compared": {"value": len(ctx.sample), "limit": 1}}
+    correct = dist_mismatch <= 0 and len(ctx.sample) >= 1
 
     dev = device or device_summary()
-    run = Run(config=cfg, traffic=traffic, queries=queries,
-              window_s=w1 - w0, setup_s=setup_s, csr_build_s=csr_build_s,
-              window_compiles=window_compiles, peak_bytes=peak,
+    run = Run(config=cfg, traffic=traffic, queries=res.queries,
+              window_s=res.window_s, setup_s=setup_s,
+              csr_build_s=csr_build_s, window_compiles=window_compiles,
+              peak_bytes=peak,
               peaks=peaks(dev["kind"]) if trace else None,
-              trace=summary, traced=queries[:n_traced])
+              trace=summary, traced=res.traced, counters=res.counters)
     metrics = {}
     for m in (c.per_layer if trace else c.end_to_end):
         value = reader(m["name"])(run)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     device_line = dict(dev, memory_peak_bytes=peak)
-    out = {"correct": bool(correct), "attempted": len(queries),
+    out = {"correct": bool(correct), "attempted": res.attempted,
            "failed": failed, "metrics": metrics, "device": device_line}
     if summary is not None:
         device_line["busy_s"] = summary["busy_s"]

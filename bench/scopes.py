@@ -19,13 +19,15 @@ of one block.  ``engine.run`` marks its host work with the spans
   in its name stack (``fusion.12 s32[1048576] (fusion Loop) @AD/WD/
   lanemap``) where it has any;
 * ``idle_gaps``: the same gaps, each named by the innermost host span
-  covering its middle, the harness's or the program's.
+  covering its middle, the loop's or the program's.
 
 A trace of a program without scopes has all its time under ``other``.
 
-    python3 bench/scopes.py <trace directory>
+    python3 bench/scopes.py <trace directory> [<loop>]
 
-prints the reduction of the one trace under the directory as JSON.
+prints the reduction of the one trace under the directory as JSON,
+naming idle gaps by the ``HOST_SPANS`` of ``bench/loops/<loop>.py``
+(``closed_one_client`` by default).
 """
 
 from __future__ import annotations
@@ -70,11 +72,12 @@ def _spans(pd, names) -> list:
             if ev.name in names]
 
 
-def reduce(pd, ops: dict) -> dict:
+def reduce(pd, ops: dict, host_spans) -> dict:
     """:func:`bench.tracing.reduce` of ``pd`` with the keys above;
-    ``ops`` is :func:`bench.xplane.tf_ops` of the same file."""
-    base = tracing.reduce(pd)
-    spans = _spans(pd, set(tracing.HOST_SPANS) | {tracing.WINDOW_SPAN}
+    ``ops`` is :func:`bench.xplane.tf_ops` of the same file and
+    ``host_spans`` the loop's span names."""
+    base = tracing.reduce(pd, host_spans)
+    spans = _spans(pd, set(host_spans) | {tracing.WINDOW_SPAN}
                    | set(PROGRAM_SPANS))
     (w0, w1), = [(s, e) for s, e, name in spans
                  if name == tracing.WINDOW_SPAN]
@@ -114,14 +117,20 @@ def reduce(pd, ops: dict) -> dict:
     return out
 
 
-def reduce_dir(log_dir: str) -> dict:
+def reduce_dir(log_dir: str, host_spans) -> dict:
     """:func:`reduce` of the one trace under ``log_dir``."""
     path = trace_file(log_dir)
     from jax.profiler import ProfileData
-    return reduce(ProfileData.from_file(str(path)), xplane.tf_ops(path))
+    return reduce(ProfileData.from_file(str(path)), xplane.tf_ops(path),
+                  host_spans)
 
 
 if __name__ == "__main__":
-    if len(sys.argv) != 2:
-        raise SystemExit("usage: python3 bench/scopes.py <trace directory>")
-    print(json.dumps(reduce_dir(sys.argv[1])))
+    if len(sys.argv) not in (2, 3):
+        raise SystemExit("usage: python3 bench/scopes.py <trace directory> "
+                         "[<loop>]")
+    from bench import harness
+    loop = harness.load_loop(sys.argv[2] if len(sys.argv) == 3
+                             else harness.DEFAULT_LOOP)
+    print(json.dumps(reduce_dir(sys.argv[1],
+                                getattr(loop, "HOST_SPANS", ()))))

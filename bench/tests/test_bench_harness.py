@@ -120,11 +120,14 @@ def test_same_keys_every_seed():
     order; only their labels differ."""
     cfg = dict(harness.cell("g500-s20-sssp").config, scale=9)
     gen = harness.load_module(harness.BENCH / "gen" / "kronecker.py")
+    stream = harness.load_module(harness.BENCH / "roots" /
+                                 "graph500_search_keys.py")
     keys = []
     for seed in (2**33 + 1, 2**33 + 2):
         src, _, _, n, labels = gen.generate(cfg, seed)
         deg = np.bincount(src, minlength=n)
-        k = harness.search_keys(deg, labels, cfg["graph_seed"])
+        k = stream.keys(deg, labels, cfg["graph_seed"],
+                        harness.cell("g500-s20-sssp").traffic)
         assert len(set(k.tolist())) == len(k) and (deg[k] > 0).all()
         assert len(k) == (deg > 0).sum()
         keys.append(np.argsort(labels)[k])        # back to the structure

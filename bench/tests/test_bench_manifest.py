@@ -72,6 +72,7 @@ def test_configs(manifest):
 
 
 def test_workloads(manifest):
+    from bench import harness
     configs = {c["name"] for c in manifest["configs"]}
     ws = manifest["workloads"]
     assert 1 <= len(ws) <= 24
@@ -85,7 +86,13 @@ def test_workloads(manifest):
         traffic = json.loads(
             (BENCH / "traffic" / f"{w['traffic']}.json").read_text())
         assert traffic["name"] == w["traffic"]
-        assert ":" in traffic["entry"]
+        entry = traffic["entry"]
+        assert all(":" in e for e in (entry.values()
+                                      if isinstance(entry, dict) else [entry]))
+        assert harness.kinds(traffic)
+        loop = traffic.get("loop", harness.DEFAULT_LOOP)
+        assert (BENCH / "loops" / f"{loop}.py").is_file()
+        assert (BENCH / "roots" / f"{traffic['roots']}.py").is_file()
     assert sum(w["chips"] == 4 for w in ws) <= max(1, len(ws) // 2)
 
 

@@ -7,9 +7,10 @@ from types import SimpleNamespace as NS
 
 import pytest
 
-from bench import scopes, tracing, xplane
+from bench import harness, scopes, tracing, xplane
 
 TRACE_DIR = Path(__file__).resolve().parents[1] / "testdata" / "trace_v5e_bfs"
+SPANS = harness.load_loop("closed_one_client").HOST_SPANS
 
 
 @pytest.fixture(scope="module")
@@ -17,7 +18,8 @@ def recorded():
     path = scopes.trace_file(str(TRACE_DIR))
     pd = tracing.load(str(TRACE_DIR))
     ops = xplane.tf_ops(path)
-    return pd, ops, tracing.reduce(pd), scopes.reduce(pd, ops)
+    return (pd, ops, tracing.reduce(pd, SPANS),
+            scopes.reduce(pd, ops, SPANS))
 
 
 def test_reader_maps_fusion_to_its_name_stack(recorded):
@@ -102,7 +104,7 @@ def _fake_trace():
 
 def test_synthetic_scopes_and_spans():
     pd, ops = _fake_trace()
-    r = scopes.reduce(pd, ops)
+    r = scopes.reduce(pd, ops, SPANS)
     assert r["scopes"]["frontier"] == pytest.approx(15e-9)
     assert r["scopes"]["relax"] == pytest.approx(40e-9)
     assert r["scopes"]["lanemap"] == pytest.approx(10e-9)
@@ -121,5 +123,5 @@ def test_synthetic_scopes_and_spans():
                               ["engine.readback", pytest.approx(10e-9)],
                               ["tally", pytest.approx(5e-9)]]
     # the existing reduction names the same gaps by the harness's spans
-    assert [n for n, _ in tracing.reduce(pd)["idle_gaps"]] == \
+    assert [n for n, _ in tracing.reduce(pd, SPANS)["idle_gaps"]] == \
         ["query", "query", "tally"]

@@ -6,9 +6,10 @@ from pathlib import Path
 
 import pytest
 
-from bench import tracing
+from bench import harness, tracing
 
 TRACE_DIR = Path(__file__).resolve().parents[1] / "testdata" / "trace_v5e_bfs"
+SPANS = harness.load_loop("closed_one_client").HOST_SPANS
 
 
 def test_union_merges_overlaps():
@@ -41,7 +42,7 @@ def test_op_names():
 @pytest.fixture(scope="module")
 def recorded():
     pd = tracing.load(str(TRACE_DIR))
-    return pd, tracing.reduce(pd)
+    return pd, tracing.reduce(pd, SPANS)
 
 
 def test_recorded_trace_numbers(recorded):
@@ -54,7 +55,7 @@ def test_recorded_trace_numbers(recorded):
     assert r["idle_share"] == pytest.approx(1 - r["busy_s"] / r["window_s"])
     # an independent count: a sweep over the op line's start and end
     # points, counting the window time in which at least one op is open
-    spans = tracing._host_spans(pd)
+    spans = tracing._host_spans(pd, SPANS)
     w0, w1 = [(s, e) for s, e, n in spans if n == tracing.WINDOW_SPAN][0]
     ops = tracing._device_ops(pd)["/device:TPU:0"]
     marks = sorted([(min(max(s, w0), w1), 1) for s, e, _ in ops]

@@ -1,8 +1,9 @@
 """Reduce a profiler trace of traced queries to device numbers.
 
-The harness wraps the traced queries in one host span,
-:data:`WINDOW_SPAN`, and each query and its tally in spans of their own
-(``query``, ``tally``).  From the ``.xplane.pb`` that
+The harness wraps the traced sub-window in one host span,
+:data:`WINDOW_SPAN`, and the loop names its host work with spans of its
+own, the loop module's ``HOST_SPANS`` (``query`` and ``tally`` for the
+closed loop).  From the ``.xplane.pb`` that
 ``jax.profiler`` writes, :func:`reduce` takes
 
 * ``busy_s``: the union of the intervals in which a device operation
@@ -14,8 +15,8 @@ The harness wraps the traced queries in one host span,
   (an enclosing loop or conditional less the operations inside it),
   named by :func:`op_name`;
 * ``idle_gaps``: the ten longest gaps between busy intervals, each
-  named by the innermost host span that covers its middle (``host``
-  where none does).
+  named by the innermost of the loop's host spans that covers its
+  middle (``host`` where none does).
 
 Only ``jax.profiler.ProfileData`` is used to read the file.
 """
@@ -26,7 +27,6 @@ import re
 from pathlib import Path
 
 WINDOW_SPAN = "traced_window"
-HOST_SPANS = ("query", "tally")
 OPS_LINE = "XLA Ops"
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 TOP = 10
@@ -53,9 +53,10 @@ def union(intervals) -> list:
     return out
 
 
-def _host_spans(pd) -> list:
-    """``(start_ns, end_ns, name)`` of the harness's host spans."""
-    names = set(HOST_SPANS) | {WINDOW_SPAN}
+def _host_spans(pd, host_spans) -> list:
+    """``(start_ns, end_ns, name)`` of the window span and of the host
+    spans named ``host_spans``."""
+    names = set(host_spans) | {WINDOW_SPAN}
     out = []
     for plane in pd.planes:
         if not plane.name.startswith("/host:"):
@@ -135,9 +136,10 @@ def _label(t: float, spans) -> str:
     return best[2] if best else "host"
 
 
-def reduce(pd) -> dict:
-    """The numbers above, from a trace holding one :data:`WINDOW_SPAN`."""
-    spans = _host_spans(pd)
+def reduce(pd, host_spans) -> dict:
+    """The numbers above, from a trace holding one :data:`WINDOW_SPAN`;
+    idle gaps are named by the spans ``host_spans``."""
+    spans = _host_spans(pd, host_spans)
     windows = [(s, e) for s, e, name in spans if name == WINDOW_SPAN]
     if len(windows) != 1:
         raise RuntimeError(f"expected one {WINDOW_SPAN!r} span, found "
